@@ -186,6 +186,9 @@ def main() -> int:
         help="report only; do not touch results/BENCH_viterbi.json",
     )
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     section = build_section(sanitize=args.sanitize)
     for line in section["lint"]["violation_lines"]:
         log.warning(line)

@@ -21,15 +21,16 @@ def load_cells(mesh: str = "single", tag: str = "") -> List[Dict]:
 
 
 def one_row(cell: Dict) -> Dict:
-    from repro.roofline.analysis import HW, roofline_report
+    from repro.roofline.analysis import hardware, roofline_report
 
     if cell["status"] != "ok":
         return {"arch": cell["arch"], "shape": cell["shape"],
                 "status": cell["status"], "reason": cell.get("reason", "")}
-    terms = roofline_report(cell)
+    hw = hardware(cell["device_kind"])
+    terms = roofline_report(cell, hw)
     mem = cell["memory_analysis"]
     fits = (mem["temp_size_in_bytes"] + mem["argument_size_in_bytes"]) \
-        < HW.hbm_bytes
+        < hw.hbm_bytes
     return {
         "arch": cell["arch"], "shape": cell["shape"], "status": "ok",
         "compute_s": terms["compute_s"], "memory_s": terms["memory_s"],

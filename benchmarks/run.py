@@ -25,6 +25,9 @@ def main():
     ap.add_argument("--quiet", action="store_true",
                     help="warnings/failures only (JSON artifacts still written)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     global log
     log = get_logger("bench.run", quiet=args.quiet)
     RESULTS.mkdir(parents=True, exist_ok=True)

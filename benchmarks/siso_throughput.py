@@ -140,6 +140,9 @@ def main() -> None:
     ap.add_argument("--full", action="store_true", help="production shapes")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     global log
     log = get_logger("bench.siso", quiet=args.quiet)
     section = run(quick=not args.full)

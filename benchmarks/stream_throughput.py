@@ -110,6 +110,7 @@ from repro.configs.paper_viterbi import DECODE_SPEC, STREAM  # noqa: E402
 from repro.core.viterbi import viterbi_decode  # noqa: E402
 from repro.decode import DecodeContext, get_decoder  # noqa: E402
 from repro.obs import Telemetry, get_logger, percentile  # noqa: E402
+from repro.parallel.mesh import make_mesh  # noqa: E402
 from repro.stream import StreamScheduler, viterbi_decode_windowed  # noqa: E402
 from repro.stream.scheduler import TICK_PHASES  # noqa: E402
 
@@ -172,7 +173,7 @@ def run_shard_scaling(args) -> None:
     n_slots = STREAM.n_slots_for(n, slots_per_shard)
     backend = args.backend or "scan"  # pure-XLA hot loop: the host-platform
     # proxy then measures scheduling + partitioning, not interpret overhead
-    mesh = jax.make_mesh((n,), (STREAM.mesh_axis,))
+    mesh = make_mesh((n,), (STREAM.mesh_axis,))
     key = jax.random.PRNGKey(0)
     info_bits = steps - spec.n_flush
     _, bm = make_workload(spec, key, n_slots, info_bits, args.flip)
@@ -210,7 +211,7 @@ def run_shard_scaling(args) -> None:
         # slot load (one partition of the same program, same process).  On
         # real multi-chip hardware the wall-clock number itself is the
         # aggregate and this branch is skipped.
-        mesh1 = jax.make_mesh((1,), (STREAM.mesh_axis,))
+        mesh1 = make_mesh((1,), (STREAM.mesh_axis,))
         bm1 = bm[:slots_per_shard]
         run_scheduler(spec, bm1, slots_per_shard, args.chunk, depth, backend,
                       mesh=mesh1)  # warm
@@ -797,6 +798,9 @@ def main():
                     help="suppress stdout reporting (warnings still print); "
                          "the JSON artifact is the output")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     get_logger("bench.stream", quiet=args.quiet)  # reconfigure module logger
     if args.chaos:
         run_chaos(args)

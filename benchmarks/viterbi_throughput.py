@@ -497,6 +497,9 @@ def main() -> None:
     ap.add_argument("--quiet", action="store_true",
                     help="warnings only (the JSON artifact is still written)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     global log
     log = get_logger("bench.viterbi", quiet=args.quiet)
     payload = run(quick=not args.full, out=args.out,

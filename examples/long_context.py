@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.core import CODE_K3_STD, bsc, encode, hard_branch_metrics
 from repro.core.viterbi import viterbi_decode, viterbi_decode_parallel
+from repro.parallel.mesh import make_mesh
 
 
 def main():
@@ -41,7 +42,7 @@ def main():
           f"assoc-scan {t_par*1e3:.0f}ms, BER={ber:.5f}")
 
     # 2: mesh-distributed (single device here -> axis size 1, same numerics)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     from repro.parallel.collectives import viterbi_decode_seqparallel
 
     with mesh:

@@ -52,9 +52,9 @@ class HotPath:
 
 
 def _unit_mesh(axis: str = "data"):
-    from jax.sharding import Mesh
+    from repro.parallel.mesh import make_mesh
 
-    return Mesh(np.asarray(jax.devices()[:1]), (axis,))
+    return make_mesh((1,), (axis,), devices=jax.devices()[:1])
 
 
 def _conv_spec():

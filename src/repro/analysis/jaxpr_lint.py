@@ -124,7 +124,7 @@ def _source_of(eqn) -> str:
 
 def _sub_jaxprs(value) -> Iterable:
     """Yield every (Closed)Jaxpr reachable from one eqn param value."""
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
 
     if isinstance(value, Jaxpr):
         yield value
@@ -201,7 +201,7 @@ def trace_contract(
     fn: Callable,
     args: Sequence,
     contract: Contract,
-) -> Tuple["jax.core.ClosedJaxpr", List[ContractViolation]]:
+) -> Tuple["jax.extend.core.ClosedJaxpr", List[ContractViolation]]:
     """Trace ``fn(*args)`` abstractly (args may be ShapeDtypeStructs) and
     check the resulting jaxpr against ``contract``.  Returns the closed
     jaxpr (so callers can report equation counts) and the violations."""

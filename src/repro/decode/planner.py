@@ -21,7 +21,8 @@ Selection policy (each branch has a planner unit test):
     ``long-conv-tiled`` routes to the time-parallel ``tiled`` backend and
     picks the tile count P by scoring ``predicted_costs()`` over candidate
     counts (``_pick_tiles``; ``parallel`` remains the fallback for
-    trellises past the tiled VMEM cap);
+    trellises past the tiled VMEM cap).  A picked P=1 is planned as
+    ``fused_packed``, the pipeline one tile runs;
   * everything else (short batched blocks) -> ``fused_packed`` (bit-packed
     survivors + on-device traceback; in-kernel branch metrics when the
     request carries raw symbols), falling back to ``parallel`` for
@@ -72,8 +73,9 @@ class DecodePlan:
         """Roofline-predicted flops/bytes of the planned decode: trace the
         backend on zeros of the planned shape and walk the jaxpr
         (roofline.jaxpr_cost, trip-count aware).  Returns {"flops", "bytes",
-        "input_bytes"} or None for backends the tracer cannot follow
-        end-to-end (host-side orchestration like the stream schedulers)."""
+        "input_bytes"}, or None for backends whose host-side orchestration
+        reads traced values (the stream schedulers' tick loops, turbo's
+        early exit).  Any other tracing failure propagates."""
         import jax.numpy as jnp
 
         from repro.roofline.jaxpr_cost import count_fn_costs
@@ -84,7 +86,8 @@ class DecodePlan:
             return count_fn_costs(
                 lambda t: self.decoder(self.spec, t, ctx=self.ctx).bits, bm
             )
-        except Exception:
+        except (jax.errors.ConcretizationTypeError,
+                jax.errors.TracerArrayConversionError):
             return None
 
     def explain(self, costs: bool = False) -> str:
@@ -158,16 +161,17 @@ def _pick_tiles(
     cost model: trace the tiled backend once per candidate P (the same
     ``predicted_costs()`` surface ``explain(costs=True)`` reports) and take
     the argmin of predicted (flops + bytes) / P — the critical path when the
-    P tiles run time-parallel on the lane axis.  Candidates that the tracer
-    cannot follow are skipped; if none trace, fall back to the shape-derived
-    default.  Cached per (spec, shape, device): planning stays cheap and
-    deterministic."""
+    P tiles run time-parallel on the lane axis.  The tiled backend is fully
+    traceable, so a candidate that fails to trace is an error, never a
+    quietly different plan.  Cached per (spec, shape, device kind): planning
+    stays cheap and deterministic."""
     from repro.kernels.tiling import MIN_TILE_CORE, default_tiles
 
     S = spec.code.n_states
-    fallback = default_tiles(B, T, S)
     cap = max(1, T // MIN_TILE_CORE)
-    candidates = sorted({p for p in (1, 2, 4, 8, 16, 32) if p <= cap} | {fallback})
+    candidates = sorted(
+        {p for p in (1, 2, 4, 8, 16, 32) if p <= cap} | {default_tiles(B, T, S)}
+    )
     scored = {}
     for p in candidates:
         plan = DecodePlan(
@@ -176,10 +180,9 @@ def _pick_tiles(
             reason="tile-count candidate", device_kind=device_kind,
         )
         c = plan.predicted_costs()
-        if c is not None:
-            scored[p] = (c["flops"] + c["bytes"]) / p
-    if not scored:
-        return fallback, "predicted_costs untraceable -> shape default"
+        if c is None:
+            raise RuntimeError(f"tiled decode at P={p} did not trace:\n{plan.explain()}")
+        scored[p] = (c["flops"] + c["bytes"]) / p
     best = min(scored, key=scored.get)
     return best, (
         f"argmin of predicted (flops+bytes)/P over P in {list(scored)} "
@@ -257,7 +260,7 @@ def plan_decode(
     ctx = ctx or DecodeContext()
     if mesh is not None:
         ctx = dataclasses.replace(ctx, mesh=mesh)
-    device_kind = jax.devices()[0].platform
+    device_kind = jax.devices()[0].device_kind
     S = spec.code.n_states
 
     fam = spec_family(spec)
@@ -317,7 +320,8 @@ def plan_decode(
                 )
             else:
                 choice = "tiled"
-                if ctx.tiles is not None:
+                pinned = ctx.tiles is not None
+                if pinned:
                     tiles, how = int(ctx.tiles), "ctx.tiles pinned by caller"
                 else:
                     tiles, how = _pick_tiles(
@@ -329,6 +333,11 @@ def plan_decode(
                     f"rule 'long-conv-tiled': time-parallel tiled decode, "
                     f"P={tiles} ({how})"
                 )
+                if tiles == 1 and not pinned:
+                    # one tile IS the plain packed pipeline: name the
+                    # backend that does the work
+                    choice = "fused_packed"
+                    reason += " -> one tile is the fused_packed pipeline"
     else:
         fused_max = get_decoder("fused_packed").capabilities.max_states
         if fused_max is not None and S > fused_max:

@@ -98,8 +98,8 @@ def _make_beta_kernel(terminated: bool):
         cost1 = (alpha
                  + jax.lax.dot(w1_ref[...], data, precision=_HI)
                  + jax.lax.dot(u1_ref[...], beta, precision=_HI))
-        out_llr_ref[...] = (jnp.min(cost1, axis=0, keepdims=True)
-                            - jnp.min(cost0, axis=0, keepdims=True))
+        out_llr_ref[0] = (jnp.min(cost1, axis=0, keepdims=True)
+                          - jnp.min(cost0, axis=0, keepdims=True))
         # retire to B_t over the new-register-bit branches
         cand0 = (jax.lax.dot(n0_ref[...], beta, precision=_HI)
                  + jax.lax.dot(c0_ref[...], data, precision=_HI))
@@ -179,8 +179,12 @@ def bcjr_beta_llr_scan(
       terminated: trellis ends in state 0 (beta init [0, inf, ...]) vs open
         (uniform beta init).
     Returns:
-      llr: (T, B) float32 — ``log P(u_t=0) - log P(u_t=1)`` in max-log
-        approximation; decide bit 1 where negative.
+      llr: (T, 1, B) float32 — ``log P(u_t=0) - log P(u_t=1)`` in max-log
+        approximation; decide bit 1 where negative.  The singleton middle
+        axis makes each step's (1, block_b) row a full-extent block, the
+        only row-block layout Mosaic accepts (a (1, block_b) block of a
+        (T, B) array is refused: its sublane dim is neither 8-aligned nor
+        the whole axis).
     """
     n0, n1, u0, u1, c0, c1, w0, w1 = mats
     T, S, B = alphas.shape
@@ -197,8 +201,8 @@ def bcjr_beta_llr_scan(
             pl.BlockSpec((1, S, block_b), rev3),
             pl.BlockSpec((1, F, block_b), rev3),
         ],
-        out_specs=[pl.BlockSpec((1, block_b), lambda b, t: (T - 1 - t, b))],
-        out_shape=[jax.ShapeDtypeStruct((T, B), jnp.float32)],
+        out_specs=[pl.BlockSpec((1, 1, block_b), rev3)],
+        out_shape=[jax.ShapeDtypeStruct((T, 1, B), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((S, block_b), jnp.float32)],
         interpret=resolve_interpret(interpret),
     )(n0, n1, u0, u1, c0, c1, w0, w1, alphas, feat)

@@ -15,8 +15,10 @@ real TPU -> compiled), and compose the full fused decoders:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -268,6 +270,9 @@ def _tile_lane_row(per_tile: np.ndarray, B: int, S: int = 1) -> jnp.ndarray:
     return jnp.asarray(v.reshape(1, -1))
 
 
+@functools.partial(
+    jax.jit, static_argnames=("code", "n_tiles", "overlap", "terminated", "interpret")
+)
 def _tiled_weighted_decode(
     code: ConvCode,
     data_btf: jnp.ndarray,
@@ -275,13 +280,17 @@ def _tiled_weighted_decode(
     n_tiles: int,
     overlap: Optional[int],
     terminated: bool,
-    interpret: Optional[bool],
+    interpret: bool,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Shared tiled-decode core (see viterbi_decode_tiled_op for the
-    contract).  data_btf: (B, T, F) user layout + (b0, b1, rb) weights."""
+    contract).  data_btf: (B, T, F) user layout + (b0, b1, rb) weights.
+
+    One jitted program, so the compiler plans its buffers: each pass
+    widens the lanes S-fold (B*P*S lanes of spans and of packed survivors),
+    and run op by op the intermediates of every pass stay alive together
+    (B=128, T=65542, P=16, S=64 then overflows a 16 GiB chip)."""
     B, T, F = data_btf.shape
     S = code.n_states
-    interpret = resolve_interpret(interpret)  # pinned across all launches
     # any overlap covering the truncation depth is promoted to the exact
     # two-pass seam resolution: strictly better and guaranteed bit-exact
     exact = overlap is None or int(overlap) >= _tiling.truncation_depth(code)
@@ -406,7 +415,7 @@ def viterbi_decode_tiled_op(
     """
     return _tiled_weighted_decode(
         code, bm_tables, _vscan.table_weights(code), n_tiles, overlap,
-        terminated, interpret,
+        terminated, resolve_interpret(interpret),  # pinned across all launches
     )
 
 
@@ -423,7 +432,8 @@ def viterbi_decode_tiled_fused(
     (B, T, M) table never exists.  received: (B, T, n_out)."""
     feats = plan.features(received, 0)
     return _tiled_weighted_decode(
-        plan.code, feats, plan.folded(), n_tiles, overlap, terminated, interpret
+        plan.code, feats, plan.folded(), n_tiles, overlap, terminated,
+        resolve_interpret(interpret),  # pinned across all launches
     )
 
 
@@ -476,7 +486,7 @@ def bcjr_llr_op(
         alphas, feat, terminated, block_b, interpret,
     )
     metric = final_pm[0, :B] if terminated else final_pm[:, :B].min(axis=0)
-    return llr[:, :B].T, metric
+    return llr[:, 0, :B].T, metric
 
 
 def minplus_matmul_op(

@@ -180,6 +180,9 @@ def run_cell(arch_id: str, shape_name: str, mesh_kind: str,
     cell = {
         "arch": arch_id, "shape": shape_name, "mesh": mesh_kind,
         "tag": tag, "status": "ok", "chips": chips,
+        # the production meshes are TPU v5e pods; the host devices only
+        # stand in for them, so the cell names the chip it was sized for
+        "device_kind": "TPU v5 lite",
         "mesh_shape": dict(mesh.shape),
         "step_kind": shape.kind,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),

@@ -12,20 +12,18 @@ Mesh shapes (TPU v5e pods):
 """
 from __future__ import annotations
 
-import jax
+from repro.parallel.mesh import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)
 
 
 def smoke_mesh():
     """Whatever devices exist, as a 1D 'data' mesh (tests / CPU runs)."""
+    import jax
+
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))

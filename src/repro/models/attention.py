@@ -163,7 +163,7 @@ def flash_decode_sharded(q1, k_cache, v_cache, lo, hi, softcap, mesh, batch_axes
     This is what lets a 500k-token cache decode even when kv_heads < 16:
     per-chip KV bytes shrink by the model-axis size regardless of head count.
     """
-    from jax.experimental.shard_map import shard_map
+    from repro.parallel.mesh import shard_map
     from jax.sharding import PartitionSpec as P
 
     S, KV = k_cache.shape[1], k_cache.shape[2]
@@ -213,7 +213,6 @@ def flash_decode_sharded(q1, k_cache, v_cache, lo, hi, softcap, mesh, batch_axes
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, s_spec, s_spec),
         out_specs=q_spec,
-        check_rep=False,
     )(q1, k_cache, v_cache, lo, hi)
 
 

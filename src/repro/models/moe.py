@@ -134,7 +134,7 @@ def moe_apply(params, cfg, x: jnp.ndarray, mesh=None) -> Tuple[jnp.ndarray, Dict
     # collectives inside (dispatch is per-row math).
     ba = _batch_axes_for(mesh, B)
     if ba:
-        from jax.experimental.shard_map import shard_map
+        from repro.parallel.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         bspec = ba if len(ba) > 1 else ba[0]
@@ -143,7 +143,6 @@ def moe_apply(params, cfg, x: jnp.ndarray, mesh=None) -> Tuple[jnp.ndarray, Dict
             mesh=mesh,
             in_specs=(P(bspec), P(bspec), P(bspec)),
             out_specs=(P(bspec), P(bspec), P(bspec), P(bspec), P(bspec)),
-            check_rep=False,
         )
         buf, slot, tok_sorted, keep, gates_sorted = disp(x, expert_idx, gate_vals)
     else:
@@ -156,7 +155,7 @@ def moe_apply(params, cfg, x: jnp.ndarray, mesh=None) -> Tuple[jnp.ndarray, Dict
     yb = jnp.einsum("becf,efd->becd", act(g) * u, params["down"]["kernel"].astype(cd))
 
     if ba:
-        from jax.experimental.shard_map import shard_map
+        from repro.parallel.mesh import shard_map
         from jax.sharding import PartitionSpec as P
 
         bspec = ba if len(ba) > 1 else ba[0]
@@ -165,7 +164,6 @@ def moe_apply(params, cfg, x: jnp.ndarray, mesh=None) -> Tuple[jnp.ndarray, Dict
             mesh=mesh,
             in_specs=(P(bspec), P(bspec), P(bspec), P(bspec)),
             out_specs=P(bspec),
-            check_rep=False,
         )
         y = comb(yb, slot, tok_sorted, gates_sorted)
     else:

@@ -504,7 +504,7 @@ def softmax_xent(logits, labels, valid=None, z_weight: float = 0.0, mesh=None):
 
 def _xent_sharded(logits, labels, mesh):
     """Vocab-sharded NLL: returns (nll (B,S), lse (B,S)) f32."""
-    from jax.experimental.shard_map import shard_map
+    from repro.parallel.mesh import shard_map
     from jax.sharding import PartitionSpec as P
 
     V = logits.shape[-1]
@@ -536,7 +536,6 @@ def _xent_sharded(logits, labels, mesh):
         f, mesh=mesh,
         in_specs=(P(bspec, None, "model"), P(bspec, None)),
         out_specs=(P(bspec, None), P(bspec, None)),
-        check_rep=False,
     )(logits, labels)
 
 

@@ -10,9 +10,14 @@ decode can be split across the ``model`` mesh axis:
   3. every shard computes the exclusive (min,+) prefix locally (n is the mesh
      axis size, so this is O(n·S^3) scalar work — negligible);
   4. each shard re-scans its chunk from the now-known boundary metrics to
-     recover backpointers, and traceback stitches bits.
+     recover backpointers, which stay on the shard that made them;
+  5. each shard traces all S exit states back through its chunk at once,
+     giving a map exit state -> entry state; one all-gather of these (small:
+     S ints) maps lets every shard chain them back from the final state to
+     its own exit state, and trace its chunk's bits from there.
 
-Communication = n · S² floats per batch element — independent of T.  This is
+Communication = n · (S² floats + S ints) per batch element — independent of
+T — and each shard holds only its own T/n steps of survivors.  This is
 the TPU-mesh analogue of the paper's "execute the custom instruction in
 parallel to other independent instructions" future-work note.
 
@@ -28,7 +33,6 @@ from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.acs import acs_step
@@ -36,6 +40,7 @@ from repro.core.trellis import NEG_UNREACHABLE, ConvCode
 from repro.core.viterbi import _traceback
 from repro.decode.spec import CodecSpec
 from repro.kernels.minplus import compose_maps, identity_map
+from repro.parallel.mesh import check_auto_mesh, shard_map
 
 
 def _local_transfer_and_bps(code: ConvCode, bm_local: jnp.ndarray):
@@ -55,6 +60,27 @@ def _local_transfer_and_bps(code: ConvCode, bm_local: jnp.ndarray):
     return mat
 
 
+def _entry_state_of_exit(code: ConvCode, bps: jnp.ndarray) -> jnp.ndarray:
+    """Trace every exit state back through a chunk's backpointers at once.
+    bps: (C, B, S).  Returns (B, S): [b, s] = the state the surviving path
+    into exit state s had when it entered the chunk — the same steps as
+    core.viterbi._traceback, on S lanes and keeping only the last state."""
+    half = code.n_states // 2
+    B, S = bps.shape[1], bps.shape[2]
+
+    def step(s, bp_t):  # s: (B, S) current state of each lane
+        v = s & (half - 1) if half > 1 else jnp.zeros_like(s)
+        # bp_t[b, s[b, k]] as a one-hot select: a gather here makes the
+        # compiler keep a second, lane-padded copy of the survivors
+        hit = s[:, :, None] == jnp.arange(S, dtype=jnp.int32)
+        j = jnp.where(hit, bp_t[:, None, :].astype(jnp.int32), 0).sum(axis=-1)
+        return 2 * v + j, None
+
+    lanes = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    entry, _ = jax.lax.scan(step, lanes, bps, reverse=True)
+    return entry
+
+
 def viterbi_decode_seqparallel(
     code: Union[ConvCode, CodecSpec],
     bm_tables: jnp.ndarray,
@@ -66,6 +92,7 @@ def viterbi_decode_seqparallel(
     the mesh axis size.  Matches the sequential decoder's metric exactly.
     ``code`` may be a bare ConvCode or a CodecSpec (whose ``terminated`` flag
     is the default when the ``terminated`` argument is omitted)."""
+    check_auto_mesh(mesh)
     spec = CodecSpec.of(code)
     code = spec.code
     if terminated is None:
@@ -94,29 +121,36 @@ def viterbi_decode_seqparallel(
         # local re-scan for backpointers
         def bp_step(pm, bm_t):
             new_pm, bp = acs_step(code, pm, bm_t)
-            return jnp.minimum(new_pm, NEG_UNREACHABLE), bp
+            # one byte per backpointer bit: a quarter of int32's memory
+            return jnp.minimum(new_pm, NEG_UNREACHABLE), bp.astype(jnp.int8)
 
         _, bps_loc = jax.lax.scan(bp_step, boundary_pm, bm_loc.swapaxes(0, 1))
         final_pm = total[:, 0, :]  # (B, S) full-sequence metrics from state 0
-        return bps_loc, final_pm
+        if terminated:
+            final_state = jnp.zeros((B,), jnp.int32)
+            metric = final_pm[:, 0]
+        else:
+            final_state = jnp.argmin(final_pm, axis=-1).astype(jnp.int32)
+            metric = final_pm.min(axis=-1)
 
-    bps_loc, final_pm = shard_map(
+        # the survivors never leave the shard: chain the per-shard exit ->
+        # entry maps back from the final state to find where this shard's
+        # stretch of the surviving path ends, then trace it from there
+        entries = jax.lax.all_gather(_entry_state_of_exit(code, bps_loc), axis)
+
+        def seam_step(exit_state, entry_map):  # walks shards n-1 .. 0
+            prev = jnp.take_along_axis(entry_map, exit_state[:, None], axis=-1)[:, 0]
+            return prev, exit_state
+
+        _, exits = jax.lax.scan(seam_step, final_state, entries, reverse=True)
+        bits_loc, _ = _traceback(code, bps_loc, exits[idx])  # (B, T/n)
+        return bits_loc, metric
+
+    return shard_map(
         shard_fn, mesh=mesh,
         in_specs=P(None, axis, None),
-        out_specs=(P(axis, None, None), P()),
-        check_rep=False,
+        out_specs=(P(None, axis), P()),
     )(bm_tables)
-    # bps_loc concatenates shard-local (T/n, B, S) blocks along time
-    bps = bps_loc  # (T, B, S) — shard_map stitches the sharded axis
-
-    if terminated:
-        final_state = jnp.zeros((B,), jnp.int32)
-        metric = final_pm[:, 0]
-    else:
-        final_state = jnp.argmin(final_pm, axis=-1).astype(jnp.int32)
-        metric = final_pm.min(axis=-1)
-    bits, _ = _traceback(code, bps, final_state)
-    return bits, metric
 
 
 def psum_scalar(x, axis: str):
@@ -165,7 +199,6 @@ def reduce_across_shards(
         mesh=mesh,
         in_specs=P(axis),
         out_specs=P(),
-        check_rep=False,
     )(jnp.asarray(per_shard))
 
 

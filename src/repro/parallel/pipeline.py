@@ -15,7 +15,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from repro.parallel.mesh import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -70,7 +70,6 @@ def pipeline_apply(
         shard_fn, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(axis),
-        check_rep=False,
     )(stage_params, x_mb)
     # ys: (n, steps, mb, ...); microbatch m exits the last stage at step m+n-1
     return ys[n - 1, n - 1 : n - 1 + M]

@@ -1,9 +1,13 @@
 from repro.roofline.analysis import (
-    HW,
+    PEAKS,
     collective_bytes,
+    hardware,
     model_flops,
     roofline_report,
     roofline_terms,
 )
 
-__all__ = ["HW", "collective_bytes", "model_flops", "roofline_terms", "roofline_report"]
+__all__ = [
+    "PEAKS", "collective_bytes", "hardware", "model_flops", "roofline_terms",
+    "roofline_report",
+]

@@ -1,10 +1,11 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms, each a lower-bound execution time in seconds (TPU v5e):
+Three terms, each a lower-bound execution time in seconds on the chip
+named by its ``device_kind`` (peaks from :data:`PEAKS`):
 
-  compute    = HLO_FLOPs_total        / (chips * 197e12)   [bf16 MXU]
-  memory     = HLO_bytes_total        / (chips * 819e9)    [HBM]
-  collective = collective_bytes_total / (chips * 50e9)     [per-link ICI]
+  compute    = HLO_FLOPs_total        / (chips * peak_flops)   [bf16 MXU]
+  memory     = HLO_bytes_total        / (chips * hbm_bw)       [HBM]
+  collective = collective_bytes_total / (chips * ici_bw)       [per-link ICI]
 
 ``cost_analysis()`` reports per-device numbers for the SPMD module; totals
 are per-device * chips, so the division by chips cancels — we compute the
@@ -26,14 +27,34 @@ from typing import Dict
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    name: str = "tpu_v5e"
-    peak_flops: float = 197e12  # bf16 per chip
-    hbm_bw: float = 819e9  # bytes/s per chip
-    ici_bw: float = 50e9  # bytes/s per link
-    hbm_bytes: float = 16 * 2 ** 30  # 16 GiB per chip
+    name: str
+    peak_flops: float  # bf16 per chip
+    hbm_bw: float  # bytes/s per chip
+    ici_bw: float  # bytes/s per link
+    hbm_bytes: float  # per chip
 
 
-HW = Hardware()
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.  TPU v5e:
+#: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s chip-to-chip over four 50 GB/s links.
+PEAKS: Dict[str, Hardware] = {
+    "TPU v5 lite": Hardware(
+        name="tpu_v5e", peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        hbm_bytes=16 * 2 ** 30,
+    ),
+}
+
+
+def hardware(device_kind: str) -> Hardware:
+    """Peaks of the chip JAX reports as ``device_kind``; a chip without
+    published peaks here is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -98,7 +119,7 @@ def roofline_terms(
     flops_per_device: float,
     bytes_per_device: float,
     collective_bytes_per_device: float,
-    hw: Hardware = HW,
+    hw: Hardware,
 ) -> Dict[str, float]:
     """All inputs are per-device (the SPMD module's numbers)."""
     compute = flops_per_device / hw.peak_flops
@@ -134,7 +155,7 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n_active * shape.global_batch
 
 
-def roofline_report(cell: dict, hw: Hardware = HW) -> dict:
+def roofline_report(cell: dict, hw: Hardware) -> dict:
     """Assemble the EXPERIMENTS.md row from one dry-run cell record.
 
     Prefers the trip-count-aware jaxpr costs (global / chips) over raw XLA

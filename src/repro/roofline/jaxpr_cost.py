@@ -30,6 +30,8 @@ _LAYOUT_PRIMS = {
     "squeeze", "slice", "rev", "bitcast_convert_type", "copy",
     "stop_gradient", "dynamic_slice", "dynamic_update_slice",
     "gather", "concatenate", "pad", "iota",
+    # Pallas ref reads/writes move data within on-chip memory, not HBM
+    "get", "swap", "program_id",
 }
 # control/bookkeeping ops: skip entirely
 _SKIP_PRIMS = {
@@ -104,8 +106,7 @@ def count_jaxpr(jaxpr, mult: float = 1.0) -> Dict[str, float]:
             flops += max(b["flops"] for b in branches)
             byts += max(b["bytes"] for b in branches)
             continue
-        if name in ("pjit", "remat2", "checkpoint", "custom_vjp_call_jaxpr",
-                    "closed_call", "core_call", "xla_call"):
+        if name in ("jit", "remat2", "closed_call"):
             sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             if sub is not None:
                 inner = count_jaxpr(getattr(sub, "jaxpr", sub), mult)
@@ -113,7 +114,10 @@ def count_jaxpr(jaxpr, mult: float = 1.0) -> Dict[str, float]:
                 byts += inner["bytes"]
             continue
         if name == "pallas_call":
-            # interpret-mode kernels: count output traffic only
+            # the kernel body's flops once per grid step; its HBM traffic
+            # is what it writes out (its reads ride the same blocks)
+            steps = float(np.prod(eqn.params["grid_mapping"].grid))
+            flops += count_jaxpr(eqn.params["jaxpr"], mult * steps)["flops"]
             byts += mult * sum(_aval_bytes(v.aval) for v in eqn.outvars)
             continue
         # default: elementwise-ish op

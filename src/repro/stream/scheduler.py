@@ -239,7 +239,9 @@ class StreamScheduler:
         self.mesh_axis = mesh_axis
         if mesh is not None:
             from repro.parallel.collectives import mesh_axis_size
+            from repro.parallel.mesh import check_auto_mesh
 
+            check_auto_mesh(mesh)
             self.n_shards = mesh_axis_size(mesh, mesh_axis)
             if not self.n_shards:
                 raise ValueError(f"mesh has no {mesh_axis!r} axis: {mesh}")

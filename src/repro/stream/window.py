@@ -401,7 +401,7 @@ def make_sharded_stream_step(
     delta, counters)`` — with every (B,)-shaped counter leaf sharded P(axis)
     alongside the slots it describes, still shard-local (no collectives).
     """
-    from jax.experimental.shard_map import shard_map
+    from repro.parallel.mesh import shard_map
     from jax.sharding import PartitionSpec as P
 
     cache_key = None
@@ -458,7 +458,6 @@ def make_sharded_stream_step(
             + ((w_specs,) if packed else ()),
             out_specs=(P(axis, None), P(None, axis, None), P(axis, None),
                        P(axis)) + ctr_specs,
-            check_rep=False,
         )
     )
 

@@ -62,7 +62,6 @@ def elastic_mesh(prefer_shape, axes, devices=None):
         # drop axes entirely until it fits (last resort: single device)
         shape = [1] * (len(prefer_shape) - 1) + [1]
     n = int(np.prod(shape))
-    arr = np.array(devices[:n]).reshape(shape)
-    from jax.sharding import Mesh
+    from repro.parallel.mesh import make_mesh
 
-    return Mesh(arr, axes)
+    return make_mesh(shape, axes, devices=devices[:n])

@@ -3,13 +3,15 @@ CPU device; only launch/dryrun.py forces 512 placeholder devices."""
 import jax
 import pytest
 
+from repro.parallel.mesh import make_mesh
+
 
 @pytest.fixture(scope="session")
 def mesh11():
     """A (1,1) ('data','model') mesh on the single CPU device — exercises
     every mesh code path (shard_map, flash decode, sharding rules) without
     multiple devices."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="session")

@@ -23,6 +23,8 @@ if os.environ.get("REPRO_MULTIDEVICE") == "1":
 import jax  # noqa: E402  (after the device-count env setup)
 import pytest  # noqa: E402
 
+from repro.parallel.mesh import make_mesh  # noqa: E402
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -40,10 +42,10 @@ def pytest_collection_modifyitems(config, items):
 @pytest.fixture(scope="session")
 def mesh81():
     """(8, 1) ('data', 'model') — every fake device on the data axis."""
-    return jax.make_mesh((8, 1), ("data", "model"))
+    return make_mesh((8, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="session")
 def mesh42():
     """(4, 2) ('data', 'model') — data sharding alongside a model axis."""
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return make_mesh((4, 2), ("data", "model"))

@@ -65,8 +65,9 @@ def test_clean_function_has_no_violations():
 
 
 def test_injected_psum_in_shard_map_is_a_collective_violation(mesh11):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.mesh import shard_map
 
     def body(x):
         return jax.lax.psum(x, "data")
@@ -94,7 +95,7 @@ def test_injected_psum_in_shard_map_is_a_collective_violation(mesh11):
 
 def test_injected_float64_constant_is_flagged_with_source_line():
     def f(x):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             y = x.astype(jnp.float64) * 1.5  # the leak
         return y.astype(jnp.float32)
 

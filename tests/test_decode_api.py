@@ -28,6 +28,7 @@ from repro.decode import (
     list_decoders,
     plan_decode,
 )
+from repro.parallel.mesh import make_mesh
 
 GRID_CODES = {"k3": CODE_K3_STD, "k7": CODE_K7_NASA}
 EXPECTED_BACKENDS = (
@@ -201,6 +202,35 @@ def test_planner_picks_tiled_for_long_blocks_without_mesh():
     assert plan.ctx.tiles is not None and plan.ctx.tiles >= 1
 
 
+def test_planner_names_fused_packed_when_one_tile_wins():
+    """At K=7 the tile cost model picks P=1 for a 1030-step frame; one tile
+    is the plain packed pipeline, so the plan names that backend."""
+    plan = plan_decode(CodecSpec(code=CODE_K7_NASA, metric="soft"), (8, 1030))
+    assert plan.backend == "fused_packed"
+    assert "P=1" in plan.reason and "one tile" in plan.reason
+    pinned = plan_decode(CodecSpec(code=CODE_K7_NASA), (8, 1030),
+                         ctx=DecodeContext(tiles=1))
+    assert pinned.backend == "tiled"
+
+
+@pytest.mark.parametrize("name,shape,backend,tiles", [
+    ("k3", (4, 1024), "tiled", 8),
+    ("k3", (4, 2048), "tiled", 16),
+    ("k7", (1024, 1030), "fused_packed", 1),  # configs tpu_nasa_frame
+    ("k7", (1, 8198), "tiled", 32),
+    ("k7", (128, 8198), "fused_packed", 1),
+    ("k7", (128, 65542), "fused_packed", 1),  # configs tpu_stream_64k
+])
+def test_tile_picks_of_the_cost_model(name, shape, backend, tiles):
+    """The tile count the roofline cost model picks for the planner's
+    shapes (soft metrics at K=7): a change to the cost walker that moves a
+    pick shows up here, not only on the chip."""
+    code = GRID_CODES[name]
+    spec = CodecSpec(code=code, metric="soft" if name == "k7" else "hard")
+    plan = plan_decode(spec, shape)
+    assert (plan.backend, plan.ctx.tiles) == (backend, tiles), plan.reason
+
+
 def test_planner_honors_pinned_tile_count():
     ctx = DecodeContext(tiles=4)
     plan = plan_decode(CodecSpec(), (4, LONG_BLOCK_T), ctx=ctx)
@@ -217,7 +247,7 @@ def test_planner_picks_seqparallel_for_long_blocks_on_mesh(mesh11):
 def test_planner_falls_back_when_mesh_lacks_axis():
     """A data-parallel-only mesh (no 'model' axis) must fall back to the
     single-device time-parallel route, not crash on the axis lookup."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     plan = plan_decode(CodecSpec(), (4, 2 * LONG_BLOCK_T), mesh=mesh)
     assert plan.backend == "tiled"
     assert "lacks axis" in plan.reason
@@ -241,6 +271,25 @@ def test_planner_picks_streaming_for_session_context():
     plan = plan_decode(CodecSpec(), (1, 10_000_000),
                        ctx=DecodeContext(streaming=True, stream_depth=15))
     assert plan.backend == "streaming"
+
+
+def test_plan_records_the_device_kind():
+    plan = plan_decode(CodecSpec(), (4, 64))
+    assert plan.device_kind == jax.devices()[0].device_kind
+
+
+def test_tile_pick_surfaces_an_untraceable_candidate(monkeypatch):
+    """A tiled candidate that fails to trace is an error, not a quietly
+    different plan (the shape default used to stand in for it)."""
+    from repro.decode import planner
+
+    monkeypatch.setattr(planner.DecodePlan, "predicted_costs", lambda self: None)
+    planner._pick_tiles.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="did not trace"):
+            plan_decode(CodecSpec(), (2, LONG_BLOCK_T + 2))
+    finally:
+        planner._pick_tiles.cache_clear()
 
 
 class _StubMesh:
@@ -299,7 +348,7 @@ def test_sharded_stream_backend_validation(mesh11):
     refuses a mesh lacking the batch axis; a unit data axis is accepted."""
     with pytest.raises(ValueError, match="mesh"):
         plan_decode(CodecSpec(), (8, 64), backend="sharded_stream")
-    model_only = jax.make_mesh((1,), ("model",))
+    model_only = make_mesh((1,), ("model",))
     with pytest.raises(ValueError, match="data"):
         plan_decode(CodecSpec(), (8, 64), backend="sharded_stream", mesh=model_only)
     plan = plan_decode(CodecSpec(), (8, 64), backend="sharded_stream", mesh=mesh11)

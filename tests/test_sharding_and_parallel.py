@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.models import common as cm
+from repro.parallel.mesh import make_mesh
 
 
 # --------------------------------------------------------------------------- #
@@ -15,7 +16,7 @@ from repro.models import common as cm
 
 
 def test_resolve_axes_divisibility(mesh11):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = dict(cm.DEFAULT_RULES)
     # kv_heads=2 under model=1: divisible, sharded (trivially)
     spec = cm.resolve_axes(mesh, rules, (8, 2, 64), ("batch", "kv_heads", None))
@@ -23,7 +24,7 @@ def test_resolve_axes_divisibility(mesh11):
 
 
 def test_resolve_axes_never_reuses_axis():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     rules = {"a": "model", "b": "model"}
     spec = cm.resolve_axes(mesh, rules, (4, 4), ("a", "b"))
     # second use of 'model' must drop, not duplicate
@@ -32,7 +33,7 @@ def test_resolve_axes_never_reuses_axis():
 
 
 def test_resolve_axes_non_dividing_drops():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     # size 3 divides 1 trivially; simulate non-division via fake rule chain
     spec = cm.resolve_axes(mesh, {"x": "missing_axis"}, (3,), ("x",))
     assert spec == P()
@@ -81,6 +82,50 @@ def test_seqparallel_viterbi_matches_sequential(mesh11, rng):
     assert (np.asarray(d_ref) == np.asarray(d_sp)).all()
 
 
+_FOUR_SHARD_SEQPARALLEL = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import CODE_K3_STD, CODE_K7_NASA, bsc, encode, hard_branch_metrics, viterbi_decode
+from repro.parallel.collectives import viterbi_decode_seqparallel
+from repro.parallel.mesh import make_mesh
+mesh = make_mesh((4,), ("model",))
+key = jax.random.PRNGKey(0)
+for code in (CODE_K3_STD, CODE_K7_NASA):
+    for terminated in (True, False):
+        # flip 0.2 on hard decisions: many tied paths, resolved alike
+        bits = jax.random.bernoulli(key, 0.5, (6, 128 - terminated * (code.constraint - 1)))
+        coded = encode(code, bits.astype(jnp.int32), terminate=terminated)
+        bm = hard_branch_metrics(code, bsc(jax.random.fold_in(key, 1), coded, 0.2))
+        want, m_want = viterbi_decode(code, bm, terminated=terminated)
+        got, m_got = viterbi_decode_seqparallel(code, bm, mesh, terminated=terminated)
+        assert got.sharding.spec == jax.sharding.PartitionSpec(None, "model")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(m_got), np.asarray(m_want))
+print("ok")
+"""
+
+
+def test_seqparallel_on_four_shards_matches_sequential():
+    """Four host devices in a fresh process: each shard traces its own
+    stretch of the survivors from the seam state the exit -> entry maps
+    give it, and the bits equal the sequential decoder's, ties included;
+    the bits come back time-sharded, never gathered."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parent.parent
+    env = dict(
+        os.environ, PYTHONPATH=str(repo / "src"), JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=4",
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", _FOUR_SHARD_SEQPARALLEL],
+        capture_output=True, text=True, timeout=600, env=env, cwd=str(repo),
+    )
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr[-3000:]
+
+
 # --------------------------------------------------------------------------- #
 # pipeline                                                                     #
 # --------------------------------------------------------------------------- #
@@ -89,7 +134,7 @@ def test_seqparallel_viterbi_matches_sequential(mesh11, rng):
 def test_pipeline_single_stage_identity(rng):
     from repro.parallel.pipeline import bubble_fraction, pipeline_apply
 
-    mesh = jax.make_mesh((1,), ("stage",))
+    mesh = make_mesh((1,), ("stage",))
     W = jax.random.normal(rng, (1, 8, 8))
 
     def layer(w, h):
@@ -105,6 +150,34 @@ def test_pipeline_single_stage_identity(rng):
 # --------------------------------------------------------------------------- #
 # roofline parsers                                                             #
 # --------------------------------------------------------------------------- #
+
+
+def test_roofline_peaks_are_keyed_by_device_kind():
+    from repro.roofline.analysis import hardware, roofline_terms
+
+    v5e = hardware("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    terms = roofline_terms(197e12, 819e9 * 2, 0.0, v5e)
+    assert terms["dominant"] == "memory_s" and terms["bound_s"] == 2.0
+    # a chip without published peaks is an error, never the v5e default
+    with pytest.raises(ValueError, match="no published peaks"):
+        hardware(jax.devices()[0].device_kind)
+
+
+def test_explicit_axis_meshes_are_rejected_with_a_pointer():
+    """jax.make_mesh's default Explicit axes break the sharded scan and the
+    slot scatter; the entry points refuse them up front."""
+    from repro.core import CODE_K3_STD
+    from repro.parallel.collectives import viterbi_decode_seqparallel
+    from repro.stream import StreamScheduler
+
+    explicit = jax.make_mesh((1,), ("model",))
+    bm = jnp.zeros((1, 8, 4), jnp.float32)
+    with pytest.raises(ValueError, match="make_mesh"):
+        viterbi_decode_seqparallel(CODE_K3_STD, bm, explicit)
+    with pytest.raises(ValueError, match="make_mesh"):
+        StreamScheduler(CODE_K3_STD, n_slots=1, mesh=jax.make_mesh((1,), ("data",)))
+    assert make_mesh((1,), ("model",)).axis_types == (jax.sharding.AxisType.Auto,)
 
 
 def test_collective_parser_shapes():

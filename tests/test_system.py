@@ -42,6 +42,30 @@ def test_trellis_expansion_count_matches_paper():
     assert paper_expansion_calls(60) == 115
 
 
+def test_compile_cache_goes_where_the_environment_says(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache sits at a fixed, git-ignored path inside the checkout.  The
+    config is restored before any compile can initialize the cache."""
+    from repro.compile_cache import DEFAULT_DIR, enable_compile_cache
+
+    repo = Path(__file__).resolve().parent.parent
+    assert DEFAULT_DIR == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().splitlines()
+    names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_DIR)
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+
+
 @pytest.mark.slow
 def test_dryrun_cell_subprocess():
     """The multi-pod dry-run machinery works end to end: lower + compile a
