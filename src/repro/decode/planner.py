@@ -42,6 +42,7 @@ from repro.decode import backends as _backends  # noqa: F401  (populates the reg
 from repro.decode.registry import RegisteredDecoder, get_decoder
 from repro.decode.request import DecodeContext, DecodeRequest, DecodeResult
 from repro.decode.spec import CodecSpec, spec_family
+from repro.obs import Telemetry, Tracer, span
 from repro.siso.turbo import TurboSpec
 
 #: family -> SISO backend the planner routes non-Viterbi specs to.
@@ -122,34 +123,44 @@ class DecodePlan:
         result.plan = self
         return result
 
-    def execute_request(self, request: "DecodeRequest") -> DecodeResult:
+    def execute_request(
+        self, request: "DecodeRequest", tracer: Optional[Tracer] = None
+    ) -> DecodeResult:
         """Run the plan on a DecodeRequest, routing raw channel output to
         the backend's in-kernel-metric entry when it has one — the bm table
         is only materialized for backends that need it.  Precomputed
         ``bm_tables`` take precedence over ``received`` (the DecodeRequest
-        contract), so callers with custom tables never get them recomputed."""
+        contract), so callers with custom tables never get them recomputed.
+
+        An attached ``tracer`` records ``decode.check`` (the host copy and
+        finiteness test of raw symbols, on that path only) and
+        ``decode.dispatch`` (the backend call up to its return, not the
+        device work)."""
         if (
             request.bm_tables is None
             and request.received is not None
             and self.decoder.from_received is not None
         ):
-            received = np.asarray(request.received)
-            if not np.isfinite(received).all():
-                # the in-kernel metric path skips every host-side table
-                # build where bad values would otherwise surface — guard
-                # here, or a single NaN symbol poisons the whole decode
-                bad = int(np.count_nonzero(~np.isfinite(received)))
-                raise ValueError(
-                    f"non-finite input: {bad} NaN/Inf value(s) in received "
-                    f"symbols {received.shape} — in-kernel branch metrics "
-                    "would silently corrupt the path metrics"
+            with span(tracer, "decode.check"):
+                received = np.asarray(request.received)
+                if not np.isfinite(received).all():
+                    # the in-kernel metric path skips every host-side table
+                    # build where bad values would otherwise surface — guard
+                    # here, or a single NaN symbol poisons the whole decode
+                    bad = int(np.count_nonzero(~np.isfinite(received)))
+                    raise ValueError(
+                        f"non-finite input: {bad} NaN/Inf value(s) in received "
+                        f"symbols {received.shape} — in-kernel branch metrics "
+                        "would silently corrupt the path metrics"
+                    )
+            with span(tracer, "decode.dispatch"):
+                result = self.decoder.decode_received(
+                    self.spec, request.received, ctx=self.ctx
                 )
-            result = self.decoder.decode_received(
-                self.spec, request.received, ctx=self.ctx
-            )
             result.plan = self
             return result
-        return self.execute(request.metrics())
+        with span(tracer, "decode.dispatch"):
+            return self.execute(request.metrics())
 
 
 @functools.lru_cache(maxsize=128)
@@ -369,6 +380,7 @@ def decode(
     mesh: Optional[object] = None,
     backend: Optional[str] = None,
     ctx: Optional[DecodeContext] = None,
+    telemetry: Optional[Telemetry] = None,
 ) -> DecodeResult:
     """One-shot decode: plan + execute.
 
@@ -378,9 +390,20 @@ def decode(
     output and the planned backend computes metrics in-kernel
     (``accepts_received``), the symbols go straight to the kernel — no
     (B, T, M) bm table is built.
+
+    ``telemetry`` with a tracer records a ``decode`` span around the call
+    and its phases inside it: ``decode.plan``, ``decode.check`` (raw-symbol
+    path only) and ``decode.dispatch``.  ``None`` (default) traces nothing;
+    the bits are the same either way.
     """
-    if not isinstance(request, DecodeRequest):
-        request = DecodeRequest(spec=_normalize_spec(request), received=received)
-    shape = request.shape()
-    plan = plan_decode(request.spec, shape, mesh=mesh, backend=backend, ctx=ctx)
-    return plan.execute_request(request)
+    tracer = None if telemetry is None else telemetry.tracer
+    with span(tracer, "decode"):
+        with span(tracer, "decode.plan"):
+            if not isinstance(request, DecodeRequest):
+                request = DecodeRequest(
+                    spec=_normalize_spec(request), received=received
+                )
+            plan = plan_decode(
+                request.spec, request.shape(), mesh=mesh, backend=backend, ctx=ctx
+            )
+        return plan.execute_request(request, tracer)

@@ -3,8 +3,8 @@
 metrics.py — counters / gauges / fixed-bucket histograms, one registry with
              ``snapshot()`` and Prometheus text exposition; the shared
              ``percentile`` helper every latency summary must use.
-trace.py   — tick-phase spans (admission / gather / step / commit / flush),
-             exported as Perfetto ``trace.json`` + JSONL; one ``is None``
+trace.py   — tick-phase spans (admission / gather / step / commit / flush)
+             and the phases of ``decode()`` and ``submit_chunk``, exported as Perfetto ``trace.json`` + JSONL; one ``is None``
              check when disabled.
 log.py     — structured key=value stdlib-logging wrapper for scripts.
 

@@ -2,8 +2,10 @@
 
 A Tracer records *complete* spans (begin timestamp + duration, Chrome trace
 ``"ph": "X"``) around the phases of a scheduler tick — admission, gather,
-forward+traceback, compaction, flush — so "where does a tick spend its
-time" is a picture, not a guess.  Design constraints, in order:
+forward+traceback, compaction, flush — and of the host work inside
+``decode()`` (plan, input check, dispatch) and ``submit_chunk`` (check,
+features, arena append, re-pin), so "where does a call spend its time" is
+a picture, not a guess.  Design constraints, in order:
 
   * off by default: every instrumented call site goes through
     :func:`span`, which returns a shared no-op context manager when the
@@ -85,10 +87,6 @@ class Tracer:
     def span(self, name: str) -> _Span:
         return _Span(self, name)
 
-    def instant(self, name: str) -> None:
-        """Zero-duration marker (admissions, evictions, compactions)."""
-        self._events.append((name, time.perf_counter_ns(), 0))
-
     def clear(self) -> None:
         self._events.clear()
 
@@ -96,6 +94,12 @@ class Tracer:
 
     def __len__(self) -> int:
         return len(self._events)
+
+    def spans(self) -> List[Tuple[str, int, int]]:
+        """Every completed span as (name, t0_ns, dur_ns), ``t0_ns`` read from
+        ``time.perf_counter_ns`` itself (absolute, not from the tracer's
+        origin) — the clock a caller can anchor to another trace."""
+        return list(self._events)
 
     def durations_s(self, name: str) -> List[float]:
         """Seconds spent in every completed span called ``name``."""

@@ -143,6 +143,7 @@ class SchedulerStats:
     slot_claims: int = 0
     steps_decoded: int = 0  # trellis steps actually consumed by streams
     arena_compactions: int = 0
+    arena_appends: int = 0  # dynamic_update_slice writes into the arena
     chunks_submitted: int = 0  # submit_chunk / producer deliveries accepted
     busy_rejections: int = 0  # StreamBusy raised by submit_chunk
     starved_slot_ticks: int = 0  # slot-ticks spent admitted-but-starved
@@ -442,33 +443,39 @@ class StreamScheduler:
         still take).  Raises StreamBusy — accepting nothing — when the chunk
         exceeds the current credit; callers throttle and retry after ticks
         drain the queue.  ``close=True`` marks EOF after accepting the rows
-        (same as a separate ``close()``)."""
-        st = self._open(stream_id)
-        if st.closed:
-            raise RuntimeError(f"stream {stream_id!r} is closed")
-        rows = np.asarray(rows, dtype=np.float32)
-        self._check_rows(rows)
-        n = rows.shape[0]
-        if n:
-            credit = st.max_buffered - st.buffered
-            if n > credit:
-                self.stats.busy_rejections += 1
-                # hint horizon: ticks until the queue can take this chunk —
-                # capped at the queue bound, since a chunk larger than
-                # max_buffered must be split and can never fit whole
-                retry = self._retry_after_ticks(
-                    st, min(n, st.max_buffered) - max(0, credit)
-                )
-                self._retry_hist.observe(retry)
-                raise StreamBusy(
-                    stream_id, max(0, credit), n, retry_after_ticks=retry
-                )
-            self._accept_rows(st, rows)
-            self.stats.chunks_submitted += 1
-        if close:
-            st.closed = True
-        self._admit()
-        return max(0, st.max_buffered - st.buffered)
+        (same as a separate ``close()``).
+
+        An attached tracer records a ``submit`` span around the call, with
+        ``submit.check``, ``submit.features``, ``submit.append`` and, on a
+        mesh, ``submit.pin`` inside it (the same children appear under the
+        tick's ``ingest``/``admit`` for producer and queued rows)."""
+        with span(self._tracer, "submit"):
+            st = self._open(stream_id)
+            if st.closed:
+                raise RuntimeError(f"stream {stream_id!r} is closed")
+            rows = np.asarray(rows, dtype=np.float32)
+            self._check_rows(rows)
+            n = rows.shape[0]
+            if n:
+                credit = st.max_buffered - st.buffered
+                if n > credit:
+                    self.stats.busy_rejections += 1
+                    # hint horizon: ticks until the queue can take this chunk —
+                    # capped at the queue bound, since a chunk larger than
+                    # max_buffered must be split and can never fit whole
+                    retry = self._retry_after_ticks(
+                        st, min(n, st.max_buffered) - max(0, credit)
+                    )
+                    self._retry_hist.observe(retry)
+                    raise StreamBusy(
+                        stream_id, max(0, credit), n, retry_after_ticks=retry
+                    )
+                self._accept_rows(st, rows)
+                self.stats.chunks_submitted += 1
+            if close:
+                st.closed = True
+            self._admit()
+            return max(0, st.max_buffered - st.buffered)
 
     def attach_producer(self, stream_id: str, producer) -> None:
         """Attach (or replace) a chunk source on an open stream — the
@@ -888,27 +895,28 @@ class StreamScheduler:
             ) from None
 
     def _check_rows(self, rows: np.ndarray) -> None:
-        expected = (
-            self.code.n_out if self.inputs == "received" else self.code.n_symbols
-        )
-        kind = "received symbols" if self.inputs == "received" else "bm tables"
-        if rows.ndim != 2 or rows.shape[1] != expected:
-            raise ValueError(
-                f"{self.inputs!r} streams take {kind} shaped (t, {expected}), "
-                f"got {rows.shape}"
+        with span(self._tracer, "submit.check"):
+            expected = (
+                self.code.n_out if self.inputs == "received" else self.code.n_symbols
             )
-        if rows.size and not np.isfinite(rows).all():
-            # a single NaN/Inf symbol would corrupt path metrics for EVERY
-            # stream in the batch tick (renormalization subtracts a max over
-            # the slot axis) — reject at the boundary, poison nothing.
-            bad = int(np.count_nonzero(~np.isfinite(rows)))
-            self.stats.poisoned_rejections += 1
-            self._poison_ctr.inc()
-            raise ValueError(
-                f"non-finite input: {bad} NaN/Inf value(s) in a {rows.shape} "
-                "chunk — non-finite symbols corrupt path metrics for the "
-                "whole batch tick"
-            )
+            kind = "received symbols" if self.inputs == "received" else "bm tables"
+            if rows.ndim != 2 or rows.shape[1] != expected:
+                raise ValueError(
+                    f"{self.inputs!r} streams take {kind} shaped (t, {expected}), "
+                    f"got {rows.shape}"
+                )
+            if rows.size and not np.isfinite(rows).all():
+                # a single NaN/Inf symbol would corrupt path metrics for EVERY
+                # stream in the batch tick (renormalization subtracts a max over
+                # the slot axis) — reject at the boundary, poison nothing.
+                bad = int(np.count_nonzero(~np.isfinite(rows)))
+                self.stats.poisoned_rejections += 1
+                self._poison_ctr.inc()
+                raise ValueError(
+                    f"non-finite input: {bad} NaN/Inf value(s) in a {rows.shape} "
+                    "chunk — non-finite symbols corrupt path metrics for the "
+                    "whole batch tick"
+                )
 
     def _accept_rows(self, st: _Stream, rows: np.ndarray) -> None:
         """Route accepted rows: straight into the arena for admitted streams,
@@ -934,9 +942,10 @@ class StreamScheduler:
         """Append a chunk to the stream's shard slab and extend its row map.
         Features are built here chunk-by-chunk (``t0=st.fed`` keeps the
         puncture phase right no matter how arrival sizes slice the stream)."""
-        data = jnp.asarray(rows)
-        if self.inputs == "received":
-            data = self._plan.features(data, t0=st.fed)
+        with span(self._tracer, "submit.features"):
+            data = jnp.asarray(rows)
+            if self.inputs == "received":
+                data = self._plan.features(data, t0=st.fed)
         start = self._append_rows(st.shard, data)
         st.rows = np.concatenate(
             [st.rows, np.arange(start, start + rows.shape[0], dtype=np.int32)]
@@ -1085,23 +1094,29 @@ class StreamScheduler:
     def _append_rows(self, shard: int, rows: jnp.ndarray) -> int:
         """Write rows into a shard's used prefix, doubling the (uniform)
         capacity as needed; returns the shard-local start row."""
-        start = self._arena_len[shard]
-        need = start + rows.shape[0]
-        cap = self._arena.shape[1]
-        if need > cap:
-            new_cap = max(2 * cap, need)
-            self._arena = jnp.concatenate(
-                [
-                    self._arena,
-                    jnp.zeros((self.n_shards, new_cap - cap, self._width), jnp.float32),
-                ],
-                axis=1,
+        with span(self._tracer, "submit.append"):
+            start = self._arena_len[shard]
+            need = start + rows.shape[0]
+            cap = self._arena.shape[1]
+            if need > cap:
+                new_cap = max(2 * cap, need)
+                self._arena = jnp.concatenate(
+                    [
+                        self._arena,
+                        jnp.zeros(
+                            (self.n_shards, new_cap - cap, self._width), jnp.float32
+                        ),
+                    ],
+                    axis=1,
+                )
+            self._arena = jax.lax.dynamic_update_slice(
+                self._arena, rows.astype(jnp.float32)[None], (shard, start, 0)
             )
-        self._arena = jax.lax.dynamic_update_slice(
-            self._arena, rows.astype(jnp.float32)[None], (shard, start, 0)
-        )
-        self._arena_len[shard] = need
-        self._pin_arena()
+            self._arena_len[shard] = need
+            self.stats.arena_appends += 1
+        if self._arena_sharding is not None:
+            with span(self._tracer, "submit.pin"):
+                self._pin_arena()
         return start
 
     def _maybe_compact(self) -> None:
