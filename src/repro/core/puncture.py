@@ -46,12 +46,17 @@ def pattern_mask(code, T: int, pattern: np.ndarray) -> jnp.ndarray:
     stream count — the turbo specs mask 1 + 2*n_parity streams, which belong
     to no single trellis.
     """
+    return jnp.asarray(host_pattern_mask(code, T, pattern))
+
+
+def host_pattern_mask(code, T: int, pattern: np.ndarray) -> np.ndarray:
+    """:func:`pattern_mask` as a float32 NumPy array, for inputs still on
+    the host."""
     n_out = code if isinstance(code, int) else code.n_out
     n, period = pattern.shape
     assert n == n_out, (n, n_out)
     reps = -(-T // period)
-    mask = np.tile(pattern.T, (reps, 1))[:T]  # (T, n_out)
-    return jnp.asarray(mask, jnp.float32)
+    return np.tile(pattern.T, (reps, 1))[:T].astype(np.float32)  # (T, n_out)
 
 
 def punctured_hard_metrics(code: ConvCode, received_bits: jnp.ndarray,

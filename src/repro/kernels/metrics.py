@@ -32,7 +32,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.puncture import pattern_mask
+from repro.core.puncture import host_pattern_mask
 from repro.core.trellis import ConvCode
 
 
@@ -46,9 +46,20 @@ def _phase_mask(
     O(T) however deep into a stream the chunk starts; a steady-state
     received session (fixed chunk, cycling phases) pays the host tile +
     device transfer once per phase, not once per push."""
+    return jnp.asarray(_host_phase_mask(code, T, pattern, phase))
+
+
+@functools.lru_cache(maxsize=None)
+def _host_phase_mask(
+    code: ConvCode, T: int, pattern: Tuple[Tuple[int, ...], ...], phase: int
+) -> np.ndarray:
+    """:func:`_phase_mask` on the host, read-only: the cache hands the same
+    array to every caller."""
     # puncture pattern is a python tuple-of-tuples — host data, not a sync
     pat = np.asarray(pattern)  # repr-lint: allow[RPR003]
-    return pattern_mask(code, phase + T, pat)[phase:]
+    mask = host_pattern_mask(code, phase + T, pat)[phase:]
+    mask.setflags(write=False)
+    return mask
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +90,19 @@ class FusedMetricPlan:
         if self.metric == "soft":
             return r * mask  # erased positions correlate to 0
         return jnp.concatenate([r * mask, jnp.broadcast_to(mask, r.shape)], axis=-1)
+
+    def host_features(self, received: np.ndarray, t0: int = 0) -> np.ndarray:
+        """:meth:`features` in NumPy, for rows still on the host: the same
+        float32 values bit for bit (a multiply by 0/1 and a concatenation),
+        in a fresh array even where no puncture applies."""
+        r = received.astype(np.float32)
+        if self.puncture is None:
+            return r
+        period = len(self.puncture[0])
+        mask = _host_phase_mask(self.code, r.shape[-2], self.puncture, t0 % period)
+        if self.metric == "soft":
+            return r * mask
+        return np.concatenate([r * mask, np.broadcast_to(mask, r.shape)], axis=-1)
 
     def bm_from_features(self, feats: jnp.ndarray) -> jnp.ndarray:
         """(..., T, F) features -> (..., T, M) bm tables: the affine form

@@ -1,11 +1,11 @@
 """Tick-phase tracing: nested wall-clock spans, Perfetto + JSONL export.
 
 A Tracer records *complete* spans (begin timestamp + duration, Chrome trace
-``"ph": "X"``) around the phases of a scheduler tick — admission, gather,
-forward+traceback, compaction, flush — and of the host work inside
-``decode()`` (plan, input check, dispatch) and ``submit_chunk`` (check,
-features, arena append, re-pin), so "where does a call spend its time" is
-a picture, not a guess.  Design constraints, in order:
+``"ph": "X"``) around the phases of a scheduler tick — admission, gather
+(with the arena write and compaction), forward+traceback, flush — and of the
+host work inside ``decode()`` (plan, input check, dispatch) and
+``submit_chunk`` (check, then accept: features and host staging), so "where
+does a call spend its time" is a picture, not a guess.  Design constraints, in order:
 
   * off by default: every instrumented call site goes through
     :func:`span`, which returns a shared no-op context manager when the
@@ -30,22 +30,35 @@ from typing import Dict, List, Optional, Tuple
 
 
 class _Span:
-    """Context manager for one live span (allocated only when tracing)."""
+    """Context manager for one live span (allocated only when tracing).
 
-    __slots__ = ("tracer", "name", "t0")
+    ``first`` and ``then`` open phases that tile a span with no gap between
+    them: a call of a few microseconds cut into separately opened children
+    would leave the tracer's own cost of opening each one unaccounted."""
 
-    def __init__(self, tracer: "Tracer", name: str):
+    __slots__ = ("tracer", "name", "t0", "t1")
+
+    def __init__(self, tracer: "Tracer", name: str, t0: Optional[int] = None):
         self.tracer = tracer
         self.name = name
+        self.t0 = t0
 
     def __enter__(self) -> "_Span":
-        self.t0 = time.perf_counter_ns()
+        if self.t0 is None:
+            self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.tracer._events.append(
-            (self.name, self.t0, time.perf_counter_ns() - self.t0)
-        )
+        self.t1 = time.perf_counter_ns()
+        self.tracer._events.append((self.name, self.t0, self.t1 - self.t0))
+
+    def first(self, name: str) -> "_Span":
+        """A child span that starts when this (open) span started."""
+        return _Span(self.tracer, name, self.t0)
+
+    def then(self, name: str) -> "_Span":
+        """A span that starts when this (closed) span ended."""
+        return _Span(self.tracer, name, self.t1)
 
 
 class _NullSpan:
@@ -58,6 +71,12 @@ class _NullSpan:
 
     def __exit__(self, *exc) -> None:
         pass
+
+    def first(self, name: str) -> "_NullSpan":
+        return self
+
+    def then(self, name: str) -> "_NullSpan":
+        return self
 
 
 _NULL_SPAN = _NullSpan()

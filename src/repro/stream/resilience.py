@@ -153,7 +153,7 @@ def snapshot_scheduler(sched) -> StreamSnapshot:
     pm_np = np.asarray(sched.state.pm)
     ring_np = np.asarray(sched.state.ring)
     offset_np = np.asarray(sched.offset)
-    arena_np = np.asarray(sched._arena)
+    arena_np = np.asarray(sched._read_arena())
     ctr_np = (
         {
             name: np.asarray(leaf)
@@ -303,7 +303,7 @@ def restore_scheduler(
                 ctrs[k][slot] = im.counters[k]
         n = im.arena_rows.shape[0] if im.arena_rows is not None else 0
         if n:
-            start = sched._append_rows(st.shard, jnp.asarray(im.arena_rows))
+            start = sched._append_rows(st.shard, im.arena_rows)
             st.rows = np.arange(start, start + n, dtype=np.int32)
     sched.state = _w.StreamState(pm=jnp.asarray(pm), ring=jnp.asarray(ring))
     sched._pin_state()

@@ -40,12 +40,13 @@ with zero branch metrics is not a no-op.  Streams that close mid-chunk
 retire through the same grouped tail-feed + batched flush as before.
 
 Per-stream input rows are **device-resident**: each accepted chunk is
-appended to one device arena and every tick gathers the (n_slots, chunk, ·)
-decode block by per-slot row indices in a single jitted take — no host-side
-numpy packing or per-tick H2D copy of symbol data on the hot path.  Chunks
-of different streams interleave in arrival order, so a stream's rows are
+given rows of one device arena and staged on the host; the next read of the
+arena (in the tick, before its gather) writes everything staged with ONE
+jitted, donated write, and every tick gathers the (n_slots, chunk, ·)
+decode block by per-slot row indices in a single jitted take.  Chunks of
+different streams interleave in arrival order, so a stream's rows are
 tracked as explicit arena row indices (not a contiguous base offset); the
-arena is compacted off the hot path when retired/consumed rows dominate.
+tick compacts the arena when retired/consumed rows dominate.
 
 **Sharding.**  Given ``mesh=``, ONE scheduler spans every device on the
 ``data`` mesh axis: the slot table is partitioned into contiguous
@@ -65,6 +66,7 @@ never change what a slot's kernel sees.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence, Tuple, Union
@@ -143,7 +145,8 @@ class SchedulerStats:
     slot_claims: int = 0
     steps_decoded: int = 0  # trellis steps actually consumed by streams
     arena_compactions: int = 0
-    arena_appends: int = 0  # dynamic_update_slice writes into the arena
+    arena_appends: int = 0  # chunks (or admitted backlogs) staged for the arena
+    arena_writes: int = 0  # device writes of the staged rows into the arena
     chunks_submitted: int = 0  # submit_chunk / producer deliveries accepted
     busy_rejections: int = 0  # StreamBusy raised by submit_chunk
     starved_slot_ticks: int = 0  # slot-ticks spent admitted-but-starved
@@ -343,6 +346,11 @@ class StreamScheduler:
         # live rows (past _compact_floor, so toy workloads never bother).
         self._arena = jnp.zeros((self.n_shards, chunk, self._width), jnp.float32)
         self._arena_len = [chunk] * self.n_shards  # used rows per shard
+        #: per shard, the float32 rows accepted but not yet written, which
+        #: end the used prefix: _arena is read only through _read_arena,
+        #: which writes them first
+        self._staged: List[List[np.ndarray]] = [[] for _ in range(self.n_shards)]
+        self._write = _arena_writer(mesh, mesh_axis)
         self._compact_ratio = 4
         self._compact_floor = 4096
         if mesh is not None:
@@ -350,6 +358,7 @@ class StreamScheduler:
             from jax.sharding import PartitionSpec as P
 
             self._arena_sharding = NamedSharding(mesh, P(mesh_axis, None, None))
+            self._start_sharding = NamedSharding(mesh, P(mesh_axis))
             self._counter_sharding = NamedSharding(mesh, P(mesh_axis))
             self.state = _w.shard_stream_state(mesh, mesh_axis, self.state)
             self._arena = jax.device_put(self._arena, self._arena_sharding)
@@ -363,6 +372,7 @@ class StreamScheduler:
             )
         else:
             self._arena_sharding = None
+            self._start_sharding = None
             self._counter_sharding = None
             self._sharded_step = None
             self._step_fn = _w.jitted_stream_step(
@@ -445,18 +455,24 @@ class StreamScheduler:
         drain the queue.  ``close=True`` marks EOF after accepting the rows
         (same as a separate ``close()``).
 
+        Accepted rows are staged on the host; the device arena receives
+        them at its next read (the next tick), in one write for all streams.
+
         An attached tracer records a ``submit`` span around the call, with
-        ``submit.check``, ``submit.features``, ``submit.append`` and, on a
-        mesh, ``submit.pin`` inside it (the same children appear under the
-        tick's ``ingest``/``admit`` for producer and queued rows)."""
-        with span(self._tracer, "submit"):
-            st = self._open(stream_id)
-            if st.closed:
-                raise RuntimeError(f"stream {stream_id!r} is closed")
-            rows = np.asarray(rows, dtype=np.float32)
-            self._check_rows(rows)
-            n = rows.shape[0]
-            if n:
+        ``submit.check`` (the stream is open, the rows are well formed and
+        within credit) and ``submit.accept`` inside it, and in the latter
+        ``submit.features`` and ``submit.append`` (the staging) for an
+        admitted stream.  The same spans appear under the tick's ``ingest``
+        for producer rows, and features and append under its ``admit`` for
+        a queued backlog."""
+        with span(self._tracer, "submit") as call:
+            with call.first("submit.check") as check:
+                st = self._open(stream_id)
+                if st.closed:
+                    raise RuntimeError(f"stream {stream_id!r} is closed")
+                rows = np.asarray(rows, dtype=np.float32)
+                self._check_rows(rows)
+                n = rows.shape[0]
                 credit = st.max_buffered - st.buffered
                 if n > credit:
                     self.stats.busy_rejections += 1
@@ -470,12 +486,12 @@ class StreamScheduler:
                     raise StreamBusy(
                         stream_id, max(0, credit), n, retry_after_ticks=retry
                     )
-                self._accept_rows(st, rows)
-                self.stats.chunks_submitted += 1
-            if close:
-                st.closed = True
-            self._admit()
-            return max(0, st.max_buffered - st.buffered)
+            with check.then("submit.accept"):
+                if n:
+                    self._accept_rows(st, rows)
+                if close:
+                    st.closed = True
+                return max(0, credit - n)
 
     def attach_producer(self, stream_id: str, producer) -> None:
         """Attach (or replace) a chunk source on an open stream — the
@@ -610,8 +626,12 @@ class StreamScheduler:
         # 2. slots with a full chunk of rows ready advance; admitted slots
         #    that are starved (open stream, no chunk yet) idle masked —
         #    their gather reads the zero prefix and their carried state is
-        #    re-selected unchanged inside stream_step.
+        #    re-selected unchanged inside stream_step.  Every row staged
+        #    since the last tick reaches the device first, in one write
+        #    (inside the compaction, when dead rows dominate the arena).
         with span(tr, "gather"):
+            self._maybe_compact()
+            arena = self._read_arena()
             ready = [
                 slot for slot, st in self.active.items()
                 if st.available >= self.chunk
@@ -641,14 +661,14 @@ class StreamScheduler:
                 if self._sharded_step is not None:
                     if self._counters is not None:
                         self.state, bits, delta, self._counters = self._sharded_step(
-                            self._arena, idx_j, mask_j, self.state, self._counters
+                            arena, idx_j, mask_j, self.state, self._counters
                         )
                     else:
                         self.state, bits, delta = self._sharded_step(
-                            self._arena, idx_j, mask_j, self.state
+                            arena, idx_j, mask_j, self.state
                         )
                 else:
-                    block = self._gather(self._arena, idx_j)  # (n_slots, chunk, ·)
+                    block = self._gather(arena, idx_j)  # (n_slots, chunk, ·)
                     weights = self._weights if self.packed else None
                     if self._counters is not None:
                         self.state, bits, delta, self._counters = self._step_fn(
@@ -895,31 +915,30 @@ class StreamScheduler:
             ) from None
 
     def _check_rows(self, rows: np.ndarray) -> None:
-        with span(self._tracer, "submit.check"):
-            expected = (
-                self.code.n_out if self.inputs == "received" else self.code.n_symbols
+        expected = (
+            self.code.n_out if self.inputs == "received" else self.code.n_symbols
+        )
+        kind = "received symbols" if self.inputs == "received" else "bm tables"
+        if rows.ndim != 2 or rows.shape[1] != expected:
+            raise ValueError(
+                f"{self.inputs!r} streams take {kind} shaped (t, {expected}), "
+                f"got {rows.shape}"
             )
-            kind = "received symbols" if self.inputs == "received" else "bm tables"
-            if rows.ndim != 2 or rows.shape[1] != expected:
-                raise ValueError(
-                    f"{self.inputs!r} streams take {kind} shaped (t, {expected}), "
-                    f"got {rows.shape}"
-                )
-            if rows.size and not np.isfinite(rows).all():
-                # a single NaN/Inf symbol would corrupt path metrics for EVERY
-                # stream in the batch tick (renormalization subtracts a max over
-                # the slot axis) — reject at the boundary, poison nothing.
-                bad = int(np.count_nonzero(~np.isfinite(rows)))
-                self.stats.poisoned_rejections += 1
-                self._poison_ctr.inc()
-                raise ValueError(
-                    f"non-finite input: {bad} NaN/Inf value(s) in a {rows.shape} "
-                    "chunk — non-finite symbols corrupt path metrics for the "
-                    "whole batch tick"
-                )
+        if rows.size and not np.isfinite(rows).all():
+            # a single NaN/Inf symbol would corrupt path metrics for EVERY
+            # stream in the batch tick (renormalization subtracts a max over
+            # the slot axis) — reject at the boundary, poison nothing.
+            bad = int(np.count_nonzero(~np.isfinite(rows)))
+            self.stats.poisoned_rejections += 1
+            self._poison_ctr.inc()
+            raise ValueError(
+                f"non-finite input: {bad} NaN/Inf value(s) in a {rows.shape} "
+                "chunk — non-finite symbols corrupt path metrics for the "
+                "whole batch tick"
+            )
 
     def _accept_rows(self, st: _Stream, rows: np.ndarray) -> None:
-        """Route accepted rows: straight into the arena for admitted streams,
+        """Route an accepted chunk: staged for the arena for admitted streams,
         host-side queue otherwise (no shard known until a slot is claimed)."""
         # latency bookkeeping: a chunk counts as committed once the commit
         # watermark passes its LAST row (fed + queued_rows is the cumulative
@@ -932,6 +951,7 @@ class StreamScheduler:
         else:
             st.queued.append(rows)
             st.queued_rows += rows.shape[0]
+        self.stats.chunks_submitted += 1
 
     def _observe_commit_latency(self, st: _Stream, now: float) -> None:
         while st.arrivals and st.arrivals[0][0] <= st.committed:
@@ -939,18 +959,22 @@ class StreamScheduler:
             self._latency_hist.observe(now - ts)
 
     def _append_stream_rows(self, st: _Stream, rows: np.ndarray) -> None:
-        """Append a chunk to the stream's shard slab and extend its row map.
-        Features are built here chunk-by-chunk (``t0=st.fed`` keeps the
-        puncture phase right no matter how arrival sizes slice the stream)."""
+        """Stage a chunk for the stream's shard slab and extend its row map.
+        Features are built here on the host, chunk-by-chunk (``t0=st.fed``
+        keeps the puncture phase right no matter how arrival sizes slice the
+        stream), into a fresh array: the caller may reuse its buffer before
+        the rows reach the device."""
         with span(self._tracer, "submit.features"):
-            data = jnp.asarray(rows)
             if self.inputs == "received":
-                data = self._plan.features(data, t0=st.fed)
-        start = self._append_rows(st.shard, data)
-        st.rows = np.concatenate(
-            [st.rows, np.arange(start, start + rows.shape[0], dtype=np.int32)]
-        )
-        st.fed += rows.shape[0]
+                data = self._plan.host_features(rows, t0=st.fed)
+            else:
+                data = np.array(rows, dtype=np.float32)
+        with span(self._tracer, "submit.append"):
+            start = self._append_rows(st.shard, data)
+            st.rows = np.concatenate(
+                [st.rows, np.arange(start, start + rows.shape[0], dtype=np.int32)]
+            )
+            st.fed += rows.shape[0]
 
     def _poll_producers(self) -> None:
         """Pull from attached producers into each stream's queue, never past
@@ -970,14 +994,15 @@ class StreamScheduler:
                     if got is not None:
                         got = np.asarray(got, dtype=np.float32)
                         if got.shape[0]:
-                            self._check_rows(got)
+                            with span(self._tracer, "submit.check"):
+                                self._check_rows(got)
                             if got.shape[0] > credit:
                                 raise ValueError(
                                     f"producer for {st.stream_id!r} returned "
                                     f"{got.shape[0]} rows against credit {credit}"
                                 )
-                            self._accept_rows(st, got)
-                            self.stats.chunks_submitted += 1
+                            with span(self._tracer, "submit.accept"):
+                                self._accept_rows(st, got)
                 if st.producer.exhausted:
                     st.closed = True
             except ValueError as e:
@@ -1060,7 +1085,7 @@ class StreamScheduler:
 
     def _pin_arena(self) -> None:
         """Re-assert the per-shard arena placement after an eager mutation
-        (chunk append, growth, compaction — all off the hot path)."""
+        (growth, compaction)."""
         if self._arena_sharding is not None:
             self._arena = jax.device_put(self._arena, self._arena_sharding)
 
@@ -1089,15 +1114,39 @@ class StreamScheduler:
             if st.queued:
                 queued, st.queued, st.queued_rows = st.queued, [], 0
                 self._append_stream_rows(st, np.concatenate(queued, axis=0))
-        self._maybe_compact()
 
-    def _append_rows(self, shard: int, rows: jnp.ndarray) -> int:
-        """Write rows into a shard's used prefix, doubling the (uniform)
-        capacity as needed; returns the shard-local start row."""
-        with span(self._tracer, "submit.append"):
-            start = self._arena_len[shard]
-            need = start + rows.shape[0]
+    def _append_rows(self, shard: int, rows: np.ndarray) -> int:
+        """Give float32 ``rows`` the next rows of a shard's used prefix and
+        stage them on the host for the next arena write; returns the
+        shard-local start row."""
+        start = self._arena_len[shard]
+        self._staged[shard].append(rows)
+        self._arena_len[shard] = start + rows.shape[0]
+        self.stats.arena_appends += 1
+        return start
+
+    def _read_arena(self) -> jax.Array:
+        """The device arena with every staged row written: the one way the
+        tick, compaction, tail feeds and snapshots read it.
+
+        Staged rows go in with ONE jitted, donated write over all shards.  A
+        shard's staged rows are the end of its used prefix (appends take the
+        next rows, and compaction reads, so writes, first), so they land as
+        one block per shard.  Blocks are padded with zeros to a power-of-two
+        row count, at least ``chunk``, so a server compiles a handful of
+        shapes; the padding falls on rows past the used prefix, which stay
+        zero, and the (uniform) capacity is doubled first where a block
+        would overrun it."""
+        if not any(self._staged):
+            return self._arena
+        with span(self._tracer, "arena.write"):
+            counts = [sum(r.shape[0] for r in staged) for staged in self._staged]
+            bucket = 1 << (max(max(counts), self.chunk) - 1).bit_length()
+            start = np.array(
+                [n - k for n, k in zip(self._arena_len, counts)], np.int32
+            )
             cap = self._arena.shape[1]
+            need = int(start.max()) + bucket
             if need > cap:
                 new_cap = max(2 * cap, need)
                 self._arena = jnp.concatenate(
@@ -1109,21 +1158,27 @@ class StreamScheduler:
                     ],
                     axis=1,
                 )
-            self._arena = jax.lax.dynamic_update_slice(
-                self._arena, rows.astype(jnp.float32)[None], (shard, start, 0)
-            )
-            self._arena_len[shard] = need
-            self.stats.arena_appends += 1
-        if self._arena_sharding is not None:
-            with span(self._tracer, "submit.pin"):
-                self._pin_arena()
-        return start
+                if self._arena_sharding is not None:
+                    with span(self._tracer, "arena.pin"):
+                        self._pin_arena()
+            block = np.zeros((self.n_shards, bucket, self._width), np.float32)
+            for shard, staged in enumerate(self._staged):
+                if staged:
+                    np.concatenate(staged, out=block[shard, : counts[shard]])
+                    staged.clear()
+            if self._arena_sharding is not None:
+                start = jax.device_put(start, self._start_sharding)
+                block = jax.device_put(block, self._arena_sharding)
+            self._arena = self._write(self._arena, start, block)
+            self.stats.arena_writes += 1
+        return self._arena
 
     def _maybe_compact(self) -> None:
         """Rebuild every shard's used prefix from its live (unconsumed)
-        segments when dead rows dominate the arena (off the hot path; keeps
-        long-lived servers bounded).  Capacity is kept when the live rows
-        fit, so the tick's compiled shape survives the compaction."""
+        segments when dead rows dominate the arena (checked once a tick,
+        before its gather; keeps long-lived servers bounded).  Capacity is
+        kept when the live rows fit, so the tick's compiled shape survives
+        the compaction."""
         live = sum(st.available for st in self.active.values()) + sum(
             st.queued_rows for st in self._by_id.values()
         )
@@ -1136,10 +1191,11 @@ class StreamScheduler:
             self._compact()
 
     def _compact(self) -> None:
+        arena = self._read_arena()
         by_shard: Dict[int, List[_Stream]] = {}
         for st in self.active.values():
             by_shard.setdefault(st.shard, []).append(st)
-        cap = self._arena.shape[1]
+        cap = arena.shape[1]
         slabs = []
         for shard in range(self.n_shards):
             parts = [jnp.zeros((self.chunk, self._width), dtype=jnp.float32)]
@@ -1148,7 +1204,7 @@ class StreamScheduler:
                 n = st.available
                 if n:
                     parts.append(
-                        jnp.take(self._arena[shard], jnp.asarray(st.rows), axis=0)
+                        jnp.take(arena[shard], jnp.asarray(st.rows), axis=0)
                     )
                 st.rows = np.arange(cursor, cursor + n, dtype=np.int32)
                 cursor += n
@@ -1183,7 +1239,7 @@ class StreamScheduler:
         """(r, M) bm tables for a stream's remaining sub-chunk tail, gathered
         from its shard's arena slab by row index (raw features go through
         the metric plan)."""
-        seg = jnp.take(self._arena[st.shard], jnp.asarray(st.rows), axis=0)
+        seg = jnp.take(self._read_arena()[st.shard], jnp.asarray(st.rows), axis=0)
         if self.inputs == "received":
             return self._plan.bm_from_features(seg)
         return seg
@@ -1295,3 +1351,35 @@ class StreamScheduler:
             st.slot = None
             del self._by_id[st.stream_id]
             self.alloc.release(slot)  # state is re-initialized at next claim
+
+
+@functools.lru_cache(maxsize=None)
+def _arena_writer(mesh, axis: str):
+    """``write(arena, start, block)``: writes shard ``s``'s rows
+    ``block[s]`` into its slab of the (n_shards, cap, W) arena from row
+    ``start[s]`` on, in place (the arena is donated).  On a mesh each chip
+    writes its own slab under shard_map, so nothing crosses chips.  One
+    jitted callable per (mesh, axis), shared by every scheduler.
+
+    A dynamic_update_slice, not a row scatter: on the TPU a (cap, W) slab
+    with W of 2 keeps its rows along the lanes, and a scatter of rows there
+    relayouts the whole arena through a lane-padded temporary."""
+
+    def write(arena, start, block):
+        # one shard's slab: (1, cap, W); a scheduler without a mesh has one
+        return jax.lax.dynamic_update_slice(arena, block, (0, start[0], 0))
+
+    if mesh is None:
+        return jax.jit(write, donate_argnums=0)
+    from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.mesh import shard_map
+
+    return jax.jit(
+        shard_map(
+            write, mesh=mesh,
+            in_specs=(P(axis, None, None), P(axis), P(axis, None, None)),
+            out_specs=P(axis, None, None),
+        ),
+        donate_argnums=0,
+    )

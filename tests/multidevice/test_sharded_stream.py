@@ -222,3 +222,77 @@ def test_sharded_submit_adapter_over_chunk_path(mesh81, rng):
         np.testing.assert_array_equal(out_a[sid][0], np.asarray(ref_bits[i]))
         np.testing.assert_array_equal(out_b[sid][0], out_a[sid][0])
         assert abs(out_a[sid][1] - out_b[sid][1]) < 1e-3
+
+
+def test_sharded_staged_writes_match_single_device_and_keep_zero_prefix(mesh81, rng):
+    """Pieces that do not divide the chunk, staged on the host and written
+    into all eight slabs by one scatter at a time: the same bits as the
+    single-device scheduler, and every shard's zero prefix stays zero."""
+    from repro.stream import StreamBusy
+
+    kw = dict(n_slots=8, chunk=16, depth=30, backend="scan")
+    single = StreamScheduler(CODE, **kw)
+    shard = StreamScheduler(CODE, mesh=mesh81, **kw)
+    tables = {
+        f"s{i}": np.asarray(
+            _noisy_bm(jax.random.fold_in(rng, 500 + i), 1, (92, 61, 128, 45)[i % 4])[1][0]
+        )
+        for i in range(12)
+    }
+    sizes = (23, 7, 41, 5)
+    for sched in (single, shard):
+        cursor = dict.fromkeys(tables, 0)
+        for sid in tables:
+            sched.open_stream(sid)
+        tick = 0
+        while any(cursor[sid] < len(t) for sid, t in tables.items()):
+            for i, (sid, table) in enumerate(tables.items()):
+                c = cursor[sid]
+                if c < len(table):
+                    piece = table[c : c + sizes[(tick + i) % len(sizes)]]
+                    try:
+                        sched.submit_chunk(sid, piece, close=c + len(piece) == len(table))
+                    except StreamBusy:
+                        continue
+                    cursor[sid] = c + len(piece)
+            writes, finished = sched.stats.arena_writes, sched.stats.streams_finished
+            sched.step()
+            # one write before the gather, and at most one more per retiring
+            # stream whose tail rows were still staged
+            assert sched.stats.arena_writes - writes <= (
+                1 + sched.stats.streams_finished - finished
+            )
+            tick += 1
+        sched.run()
+    assert shard.stats.arena_writes > 0
+    for sid in tables:
+        np.testing.assert_array_equal(shard.results[sid][0], single.results[sid][0])
+        assert abs(shard.results[sid][1] - single.results[sid][1]) < 1e-4
+    arena = np.asarray(shard._read_arena())
+    for s, n in enumerate(shard._arena_len):
+        assert not arena[s, : shard.chunk].any(), s
+        assert not arena[s, n:].any(), s
+
+
+def test_sharded_arena_write_holds_no_collective(mesh81):
+    """Compiled for the mesh, the write of the staged rows fills each
+    shard's slab on its own device (no collective) and updates the donated
+    arena in place."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.stream.scheduler import _arena_writer
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh81, spec))
+
+    compiled = _arena_writer(mesh81, "data").lower(
+        sds((8, 4096, 2), jnp.float32, P("data", None, None)),
+        sds((8,), jnp.int32, P("data")),
+        sds((8, 512, 2), jnp.float32, P("data", None, None)),
+    ).compile()
+    hlo = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all", "collective-permute",
+               "reduce-scatter"):
+        assert op not in hlo, op
+    assert "input_output_alias" in hlo

@@ -193,6 +193,28 @@ def test_tracer_spans_are_absolute_perf_counter_times():
     assert len(tr) == 2
 
 
+def test_phase_spans_tile_their_parent():
+    """``first`` starts a child when its parent started and ``then`` starts a
+    span when the previous one ended, so phases cover their parent with no
+    gap for the tracer's own cost; disabled, they are the shared no-op."""
+    tr = Tracer("test")
+    with span(tr, "call") as call:
+        with call.first("check") as check:
+            time.sleep(0.001)
+        with check.then("accept"):
+            pass
+    (_, t_a, d_a), (_, t_b, d_b), (_, t_p, d_p) = tr.spans()
+    assert t_a == t_p and t_b == t_a + d_a and t_b + d_b <= t_p + d_p
+    assert 0.9 < tr.coverage("call", ("check", "accept")) <= 1.0
+    off = span(None, "call")
+    with off as call:
+        with call.first("check") as check:
+            pass
+        with check.then("accept") as accept:
+            pass
+    assert call is check is accept is off
+
+
 def test_tracer_chrome_and_jsonl_export(tmp_path):
     tr = Tracer("proc-name")
     with span(tr, "tick"):
@@ -592,6 +614,9 @@ def test_decode_spans_cover_the_call_and_leave_bits_unchanged(rng, inputs):
 
 @pytest.mark.parametrize("sharded", [False, True])
 def test_submit_chunk_spans_its_phases(request, rng, sharded):
+    """submit_chunk stages on the host: its spans cover the call, no device
+    write or arena re-placement runs per chunk, and the next tick writes
+    every staged row at once, in one ``arena.write`` inside its gather."""
     mesh = request.getfixturevalue("mesh11") if sharded else None
     tel = Telemetry.enabled(device_counters=False)
     sched = StreamScheduler(CODE, n_slots=2, chunk=16, depth=30, backend="scan",
@@ -603,14 +628,26 @@ def test_submit_chunk_spans_its_phases(request, rng, sharded):
     tr = tel.tracer
     names = [n for n, _, _ in tr.spans()]
     assert names.count("submit") == 4
-    for child in ("submit.check", "submit.features", "submit.append"):
+    for child in ("submit.check", "submit.accept", "submit.features", "submit.append"):
         assert names.count(child) == 4, child
-    # the whole-arena re-placement exists only on a mesh
-    assert names.count("submit.pin") == (4 if sharded else 0)
-    children = ("submit.check", "submit.features", "submit.append", "submit.pin")
-    assert 0.9 <= tr.coverage("submit", children) <= 1.0
+    # nothing per chunk touches the device: no write, no re-placement
+    assert not set(names) & {"submit.pin", "arena.write", "arena.pin"}
+    # features and append nest inside accept
+    assert 0.9 <= tr.coverage("submit", ("submit.check", "submit.accept")) <= 1.0
     # none of the names collides with a tick phase the benchmark reads
     assert not set(names) & {"tick", *TICK_PHASES}
+
+    tr.clear()
+    sched.step()
+    spans = tr.spans()
+    (write,) = [s for s in spans if s[0] == "arena.write"]
+    (gather,) = [s for s in spans if s[0] == "gather"]
+    assert gather[1] <= write[1] and write[1] + write[2] <= gather[1] + gather[2]
+    # 64 staged rows outgrew the 16-row arena: re-placed once, on a mesh only
+    assert [s[0] for s in spans].count("arena.pin") == (1 if sharded else 0)
+    tr.clear()
+    sched.step()  # nothing staged since: no write
+    assert "arena.write" not in [n for n, _, _ in tr.spans()]
 
 
 def test_arena_appends_count_every_append_and_survive_restore(rng):
@@ -642,6 +679,39 @@ def test_arena_appends_count_every_append_and_survive_restore(rng):
     assert restored.stats.arena_appends == 3
     for sid in ("a", "b"):
         np.testing.assert_array_equal(sched.results[sid][0], restored.results[sid][0])
+
+
+def test_arena_writes_one_per_tick_with_staged_rows(rng):
+    """One device write per tick that found rows staged, compaction ticks
+    included (the compaction writes them first), and none for a tick that
+    found none; appends over writes is the chunks each write batched."""
+    sched = StreamScheduler(CODE, n_slots=3, chunk=16, depth=30, backend="scan")
+    sched._compact_floor = 0
+    sched._compact_ratio = 1  # compact whenever consumed rows are left
+    tables = {sid: np.asarray(bm) for sid, bm in _make_streams(rng, 3).items()}
+    for sid, t in tables.items():
+        sched.open_stream(sid)
+        sched.submit_chunk(sid, t[:16])  # one chunk ahead: rows stay live
+    compacted_with_staged = 0
+    for k in range(16, 96, 16):
+        for sid, t in tables.items():
+            sched.submit_chunk(sid, t[k:k + 16], close=k + 16 >= len(t))
+        writes, compactions = sched.stats.arena_writes, sched.stats.arena_compactions
+        sched.step()
+        assert sched.stats.arena_writes == writes + 1
+        compacted_with_staged += sched.stats.arena_compactions - compactions
+    assert compacted_with_staged > 0
+    writes = sched.stats.arena_writes
+    sched.step()  # consumes the last chunks; nothing was staged
+    assert sched.stats.arena_writes == writes == 5
+    assert sched.stats.arena_appends == sched.stats.chunks_submitted == 18
+    assert sched.metrics_snapshot()["scheduler_arena_writes"] == 5
+    out = sched.run()
+    whole = _run_workload(
+        StreamScheduler(CODE, n_slots=3, chunk=16, depth=30, backend="scan"), tables
+    )
+    for sid in tables:
+        np.testing.assert_array_equal(out[sid][0], whole[sid][0])
 
 
 # --------------------------------------------------------------------------- #

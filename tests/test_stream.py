@@ -524,8 +524,8 @@ def test_scheduler_zero_length_stream(rng):
 
 
 def test_scheduler_compaction_mid_tick_with_live_slots(rng):
-    """Compaction triggered between ticks while streams are mid-flight (the
-    admit path compacts): live segments must be relocated coherently so the
+    """Compaction triggered while streams are mid-flight (the tick compacts
+    before its gather): live segments must be relocated coherently so the
     in-flight decode continues bit-exact."""
     code = CODE_K3_STD
     sched = StreamScheduler(code, n_slots=2, chunk=16, depth=15, backend="scan")
@@ -763,3 +763,196 @@ def test_scheduler_chunk_fed_submit_tick_compact_interleaving(rng):
     assert sched.stats.arena_compactions > 0
     for sid, rb in refs.items():
         np.testing.assert_array_equal(sched.results[sid][0], rb)
+
+
+# --------------------------------------------------------------------------- #
+# (k) host-staged arena: one device write per tick                             #
+# --------------------------------------------------------------------------- #
+
+
+class _EagerAppendScheduler(StreamScheduler):
+    """The reference for the staged arena: every accepted chunk's features
+    built on the device and written with its own eager dynamic_update_slice,
+    the arena grown and re-placed at that append, nothing staged."""
+
+    def _append_stream_rows(self, st, rows):
+        data = jnp.asarray(rows)
+        if self.inputs == "received":
+            data = self._plan.features(data, t0=st.fed)
+        start = self._append_rows(st.shard, data)
+        st.rows = np.concatenate(
+            [st.rows, np.arange(start, start + rows.shape[0], dtype=np.int32)]
+        )
+        st.fed += rows.shape[0]
+
+    def _append_rows(self, shard, rows):
+        start = self._arena_len[shard]
+        need = start + rows.shape[0]
+        cap = self._arena.shape[1]
+        if need > cap:
+            grow = jnp.zeros(
+                (self.n_shards, max(2 * cap, need) - cap, self._width), jnp.float32
+            )
+            self._arena = jnp.concatenate([self._arena, grow], axis=1)
+        self._arena = jax.lax.dynamic_update_slice(
+            self._arena, jnp.asarray(rows, jnp.float32)[None], (shard, start, 0)
+        )
+        self._arena_len[shard] = need
+        self.stats.arena_appends += 1
+        self._pin_arena()
+        return start
+
+
+def _drive(sched, tables, sizes, restore_at=None):
+    """Feed every stream its table in pieces of ``sizes`` rows (cycling,
+    round robin, one piece per stream between ticks), closing it with its
+    last piece; ``restore_at``: snapshot the scheduler before that tick,
+    with rows staged, and go on from the restored one.  Returns the
+    scheduler that finished and its results."""
+    from repro.stream import StreamBusy
+
+    cursor = dict.fromkeys(tables, 0)
+    for sid in tables:
+        sched.open_stream(sid)
+    tick = 0
+    while any(cursor[sid] < len(t) for sid, t in tables.items()):
+        for i, (sid, table) in enumerate(tables.items()):
+            c = cursor[sid]
+            if c >= len(table):
+                continue
+            piece = table[c : c + sizes[(tick + i) % len(sizes)]]
+            try:
+                sched.submit_chunk(sid, piece, close=c + len(piece) == len(table))
+            except StreamBusy:
+                continue
+            cursor[sid] = c + len(piece)
+        if tick == restore_at:
+            assert any(sched._staged)
+            sched = StreamScheduler.restore(sched.snapshot())
+        sched.step()
+        tick += 1
+    return sched, sched.run()
+
+
+_STAGING_CASES = {
+    # pieces that do not divide the chunk, streams admitted at once
+    "ragged": dict(n_slots=3, streams=3, inputs="bm"),
+    # raw symbols of a rate-2/3 code: the host features' puncture phase
+    # follows each piece's first step
+    "punctured": dict(n_slots=3, streams=3, inputs="received"),
+    # one slot: the other streams' pieces queue on the host and land as
+    # one backlog when a slot frees
+    "backlog": dict(n_slots=1, streams=3, inputs="bm"),
+    # snapshot with rows staged, restored and driven to the end
+    "restore": dict(n_slots=2, streams=3, inputs="bm", restore_at=2),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STAGING_CASES))
+def test_staged_arena_matches_eager_appends(case, rng):
+    """Host staging plus one write per tick leaves every arena row and every
+    decoded bit as the per-chunk eager device append did, bit for bit."""
+    from repro.core.puncture import PUNCTURE_2_3
+    from repro.decode import CodecSpec
+
+    cfg = _STAGING_CASES[case]
+    code = CODE_K3_STD
+    keys = [jax.random.fold_in(rng, 300 + i) for i in range(cfg["streams"])]
+    lengths = (118, 77, 150)
+    if cfg["inputs"] == "received":
+        spec = CodecSpec(code=code, puncture=PUNCTURE_2_3)
+        kw = dict(backend="fused_packed", inputs="received", chunk=32, depth=64)
+        tables = {}
+        for i, key in enumerate(keys):
+            bits = jax.random.bernoulli(key, 0.5, (1, lengths[i])).astype(jnp.int32)
+            rx = spec.channel(jax.random.fold_in(key, 1), spec.encode(bits),
+                              flip_prob=0.02)
+            tables[f"s{i}"] = np.asarray(rx[0], np.float32)
+    else:
+        spec = code
+        kw = dict(backend="scan", chunk=16, depth=30)
+        tables = {
+            f"s{i}": np.asarray(_noisy_bm(code, key, 1, lengths[i], 0.02)[1][0])
+            for i, key in enumerate(keys)
+        }
+    sizes = (23, 7, 41, 5)
+    staged, got = _drive(StreamScheduler(spec, n_slots=cfg["n_slots"], **kw),
+                         tables, sizes, restore_at=cfg.get("restore_at"))
+    eager, want = _drive(_EagerAppendScheduler(spec, n_slots=cfg["n_slots"], **kw),
+                         tables, sizes)
+    assert set(got) == set(want) == set(tables)
+    for sid in tables:
+        np.testing.assert_array_equal(got[sid][0], want[sid][0])
+        assert got[sid][1] == want[sid][1]
+    if case != "restore":  # a restored arena is laid out anew
+        assert staged._arena_len == eager._arena_len
+        for shard, n in enumerate(staged._arena_len):
+            a = np.asarray(staged._read_arena()[shard, :n])
+            b = np.asarray(eager._arena[shard, :n])
+            np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
+        assert staged.stats.arena_appends == eager.stats.arena_appends
+        assert 0 < staged.stats.arena_writes <= staged.stats.arena_appends
+
+
+@pytest.mark.parametrize("metric", ["hard", "soft"])
+@pytest.mark.parametrize("pattern", ["2_3", "3_4"])
+def test_host_features_match_device_features_at_every_phase(metric, pattern, rng):
+    """The NumPy features the scheduler stages equal FusedMetricPlan.features
+    bit for bit, for every start step in the puncture period and lengths
+    that cross it, negative zeros included."""
+    from repro.core import puncture
+    from repro.kernels.metrics import fused_metric_plan
+
+    pat = getattr(puncture, f"PUNCTURE_{pattern}")
+    plan = fused_metric_plan(CODE_K3_STD, metric, pat)
+    rx = np.array(jax.random.normal(rng, (41, 2)), np.float32)
+    rx[3] = -0.0
+    for t0 in range(2 * pat.shape[1]):
+        for T in (1, 5, 41):
+            want = np.asarray(plan.features(jnp.asarray(rx[:T]), t0=t0))
+            got = plan.host_features(rx[:T], t0=t0)
+            assert got.dtype == np.float32 and got.shape == want.shape
+            np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_padded_arena_writes_keep_the_zero_prefix(request, sharded, rng):
+    """Pieces that never fill a power-of-two block pad every write; the pad
+    entries are dropped, so rows [0, chunk) of each shard stay zero (the
+    read target of starved slots) and no row past the used prefix is
+    touched."""
+    mesh = request.getfixturevalue("mesh11") if sharded else None
+    code = CODE_K3_STD
+    sched = StreamScheduler(code, n_slots=2, chunk=16, depth=30, backend="scan",
+                            mesh=mesh)
+    tables = {
+        f"s{i}": np.asarray(_noisy_bm(code, jax.random.fold_in(rng, 400 + i), 1,
+                                      (61, 93)[i], 0.02)[1][0]) + 1.0
+        for i in range(2)
+    }
+    sched, _ = _drive(sched, tables, (3, 11, 17))
+    assert sched.stats.arena_writes > 0
+    arena = np.asarray(sched._read_arena())
+    for shard, n in enumerate(sched._arena_len):
+        assert not arena[shard, : sched.chunk].any()
+        assert not arena[shard, n:].any()
+        assert (arena[shard, sched.chunk : n] != 0).any()
+
+
+def test_submit_chunk_copies_the_callers_rows(rng):
+    """Rows wait on the host until the tick writes them: a caller that
+    reuses its buffer right after submit_chunk returns changes nothing."""
+    code = CODE_K3_STD
+    _, bm = _noisy_bm(code, rng, 1, 94, 0.02)
+    table = np.asarray(bm[0])
+    ref, _ = viterbi_decode(code, bm)
+    sched = StreamScheduler(code, n_slots=1, chunk=16, depth=30, backend="scan")
+    sched.open_stream("s")
+    buf = np.empty((16, table.shape[1]), np.float32)
+    for k in range(0, len(table), 16):
+        piece = table[k : k + 16]
+        buf[: len(piece)] = piece
+        sched.submit_chunk("s", buf[: len(piece)], close=k + 16 >= len(table))
+        buf[:] = np.nan  # the next receive overwrites the buffer
+        sched.step()
+    np.testing.assert_array_equal(sched.run()["s"][0], np.asarray(ref[0]))
