@@ -264,10 +264,16 @@ def decode_turbo(spec, llrs, *, ctx: DecodeContext) -> DecodeResult:
     """Iterative turbo decoder: two BCJR SISO passes per iteration exchanging
     scaled extrinsic LLRs through the spec's interleaver, early-exiting on
     LLR-sign agreement.  ``path_metric`` is the negated mean posterior |LLR|
-    (lower = more confident, matching the minimized-metric convention)."""
+    (lower = more confident, matching the minimized-metric convention).
+    ``ctx.telemetry`` receives the loop's spans and counters."""
     from repro.siso.turbo import turbo_decode
 
-    result = turbo_decode(spec, llrs, interpret=ctx.interpret)
+    tel = ctx.telemetry
+    result = turbo_decode(
+        spec, llrs, interpret=ctx.interpret,
+        metrics=None if tel is None else tel.metrics,
+        tracer=None if tel is None else tel.tracer,
+    )
     metric = -jnp.mean(jnp.abs(result.llr), axis=-1)
     return _result(
         spec, result.bits, metric, backend="turbo",

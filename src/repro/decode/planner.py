@@ -275,6 +275,11 @@ def plan_decode(
     S = spec.code.n_states
 
     fam = spec_family(spec)
+    if fam == "turbo" and T != spec.n_steps(spec.block_len):
+        raise ValueError(
+            f"a {spec.describe()} block is {spec.n_steps(spec.block_len)} rows "
+            f"(K={spec.block_len} + {spec.n_tail_rows} tail rows), got T={T}"
+        )
     if backend is not None:
         choice, reason = backend, f"explicit backend={backend!r} override"
     elif fam in FAMILY_BACKENDS:
@@ -393,12 +398,15 @@ def decode(
 
     ``telemetry`` with a tracer records a ``decode`` span around the call
     and its phases inside it: ``decode.plan``, ``decode.check`` (raw-symbol
-    path only) and ``decode.dispatch``.  ``None`` (default) traces nothing;
-    the bits are the same either way.
+    path only) and ``decode.dispatch``.  It also reaches the backend as
+    ``ctx.telemetry`` (the ``turbo`` loop records its iterations there).
+    ``None`` (default) traces nothing; the bits are the same either way.
     """
     tracer = None if telemetry is None else telemetry.tracer
     with span(tracer, "decode"):
         with span(tracer, "decode.plan"):
+            if telemetry is not None:
+                ctx = dataclasses.replace(ctx or DecodeContext(), telemetry=telemetry)
             if not isinstance(request, DecodeRequest):
                 request = DecodeRequest(
                     spec=_normalize_spec(request), received=received

@@ -43,6 +43,10 @@ class DecodeContext:
         exact min-plus seam resolution (bit-exact); smaller values select
         the cheaper truncated warm-up approximation.
       interpret: force Pallas interpret mode (None = auto: interpret off-TPU).
+      telemetry: the caller's repro.obs Telemetry, for backends that record
+        inside themselves (``turbo``: its iteration spans and counters);
+        ``decode(telemetry=...)`` puts it here.  None records nothing.  An
+        observer, not part of how to decode: left out of equality and hash.
     """
 
     mesh: Optional[object] = None
@@ -54,6 +58,7 @@ class DecodeContext:
     tiles: Optional[int] = None
     tile_overlap: Optional[int] = None
     interpret: Optional[bool] = None
+    telemetry: Optional[Any] = dataclasses.field(default=None, compare=False)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import cached_property
+from typing import Dict, Tuple
 
 import numpy as np
 
@@ -79,3 +80,26 @@ class QPPInterleaver:
     @cached_property
     def inverse(self) -> np.ndarray:
         return np.argsort(self.permutation).astype(np.int32)
+
+
+#: 3GPP TS 36.212 Table 5.1.3-3, turbo code internal interleaver parameters:
+#: code-block size K -> (f1, f2), each with its row index i of the table.
+#: Only the rows this repository states are here (no copy of the table is
+#: fetched); add a size only with its row.
+LTE_QPP: Dict[int, Tuple[int, int]] = {
+    40: (3, 10),      # i = 1
+    64: (7, 16),      # i = 4
+    512: (31, 64),    # i = 60
+    6144: (263, 480),  # i = 188
+}
+
+
+def lte_qpp(k: int) -> QPPInterleaver:
+    """The 36.212 QPP interleaver of code-block size ``k`` (Table 5.1.3-3)."""
+    try:
+        f1, f2 = LTE_QPP[k]
+    except KeyError:
+        raise ValueError(
+            f"no 36.212 QPP row for K={k} here (known: {sorted(LTE_QPP)})"
+        ) from None
+    return QPPInterleaver(k, f1, f2)

@@ -4,9 +4,24 @@ A TurboSpec is the turbo-family analogue of CodecSpec: constituent RSC code
 + interleaver + optional puncture pattern + iteration policy, hashable so it
 keys jit caches and the decode registry the same way CodecSpec does.  The
 encoder emits [systematic, parity1, parity2(interleaved input)] — the
-classic rate-1/3 parallel concatenation; both constituent trellises are
-left open (no tails), which keeps the rate exactly 1/(1 + 2*n_parity) and
-both SISO passes shape-identical (one kernel compilation serves both).
+classic rate-1/3 parallel concatenation.  By default both constituent
+trellises are left open (no tails), which keeps the rate exactly
+1/(1 + 2*n_parity) and both SISO passes shape-identical (one kernel
+compilation serves both).
+
+``tail="36.212"`` is the LTE code of 3GPP TS 36.212 5.1.3.2: each
+constituent is driven back to state 0 by three tail steps taking their
+input from the feedback (5.1.3.2.2), and the 12 tail bits ride in four
+extra rows, so a block of K bits is (K + 4, 3) coded bits:
+
+  row K     x_K      z_K      x_{K+1}     (encoder 1's tail)
+  row K+1   z_{K+1}  x_{K+2}  z_{K+2}
+  row K+2   x'_K     z'_K     x'_{K+1}    (encoder 2's tail)
+  row K+3   z'_{K+1} x'_{K+2} z'_{K+2}
+
+The decoder hands each constituent its own tail: both SISO passes run
+T = K + 3 steps with beta seeded at state 0, the a-priori input is 0 on the
+tail steps, and extrinsic values are exchanged over the first K only.
 
 Decode loop (all LLRs min-domain, ``lambda = log P(0)/P(1)``):
 
@@ -23,7 +38,10 @@ construction (gated in tests), and the loop stops once every stream froze.
 
 Observability: pass ``metrics=MetricsRegistry()`` (repro.obs) and the loop
 records per-iteration LLR-sign agreement, iteration counts, converged
-streams, and early exits.
+streams, and early exits; pass a ``tracer`` and it records the spans
+``turbo`` ⊃ ``turbo.iteration`` ⊃ ``turbo.dispatch`` (the iteration's jitted
+call, to its return) and ``turbo.sync`` (the host reads of the agreement
+and the all-frozen test).
 """
 from __future__ import annotations
 
@@ -38,10 +56,14 @@ import numpy as np
 from repro.core.channel import awgn, bpsk_modulate
 from repro.core.puncture import pattern_mask
 from repro.kernels.ops import bcjr_llr_op
+from repro.obs import span
 from repro.siso.interleave import BlockInterleaver, QPPInterleaver
 from repro.siso.rsc import RSC_K3_75, RSCCode
 
 InterleaverSpec = Union[BlockInterleaver, QPPInterleaver]
+
+#: Trellis terminations a TurboSpec knows: open, or 3GPP TS 36.212 5.1.3.2.2.
+TAILS = ("none", "36.212")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,6 +82,8 @@ class TurboSpec:
       extrinsic_scale: damping on the exchanged extrinsic LLRs.  Max-log
         SISO overestimates reliability; the classic 0.7 scaling recovers
         most of the gap to true log-MAP (Vogt & Finger 2000).
+      tail: ``"none"`` (open trellises) or ``"36.212"`` (both constituents
+        terminated, the 12 tail bits in 4 extra rows; module docstring).
     """
 
     code: RSCCode = RSC_K3_75
@@ -68,10 +92,22 @@ class TurboSpec:
     iterations: int = 6
     early_exit: bool = True
     extrinsic_scale: float = 0.7
+    tail: str = "none"
 
     def __post_init__(self):
         if self.iterations < 1:
             raise ValueError("iterations must be >= 1")
+        if self.tail not in TAILS:
+            raise ValueError(f"tail must be one of {TAILS}, got {self.tail!r}")
+        if self.tail == "36.212" and (
+            self.code.constraint != 4 or self.code.n_parity != 1
+            or self.puncture is not None
+        ):
+            raise ValueError(
+                "the 36.212 tail layout is for unpunctured rate-1/3 codes with "
+                f"K=4 constituents; got K={self.code.constraint}, "
+                f"{self.code.n_parity} parities, puncture={self.puncture}"
+            )
         if self.puncture is not None:
             pat = np.asarray(self.puncture)
             if pat.ndim != 2 or pat.shape[0] != self.n_streams:
@@ -100,8 +136,14 @@ class TurboSpec:
 
     @property
     def terminated(self) -> bool:
-        """Constituent trellises are left open (no tail bits)."""
-        return False
+        """Constituent trellises end in state 0 (``tail="36.212"``)."""
+        return self.tail != "none"
+
+    @property
+    def n_tail_rows(self) -> int:
+        """Rows of tail bits after the K information rows: the 12 tail bits
+        of 36.212 in 4 rows of 3, or none."""
+        return 4 if self.terminated else 0
 
     @property
     def metric(self) -> str:
@@ -121,22 +163,32 @@ class TurboSpec:
         return self.n_streams
 
     def n_steps(self, n_info_bits: int) -> int:
-        return n_info_bits
+        """Input rows of a block of ``n_info_bits``: tail rows included."""
+        return n_info_bits + self.n_tail_rows
 
     # --------------------------- encode side --------------------------- #
 
     def encode(self, bits: jnp.ndarray) -> jnp.ndarray:
-        """(..., N) info bits -> (..., N, n_streams) coded bits, N =
-        interleaver.n; punctured positions zeroed (not transmitted)."""
+        """(..., N) info bits -> (..., N + n_tail_rows, n_streams) coded
+        bits, N = interleaver.n; punctured positions zeroed (not
+        transmitted).  With the 36.212 tail the last four rows are the 12
+        tail bits in the standard's order (module docstring)."""
         if bits.shape[-1] != self.block_len:
             raise ValueError(
                 f"turbo block length is fixed by the interleaver: expected "
                 f"{self.block_len} info bits, got {bits.shape[-1]}"
             )
+        N = self.block_len
         perm = jnp.asarray(self.interleaver.permutation)
-        c1 = self.code.encode(bits, terminate=False)  # (..., N, 1 + n_parity)
-        c2 = self.code.encode(bits[..., perm], terminate=False)
-        coded = jnp.concatenate([c1, c2[..., 1:]], axis=-1)
+        # (..., N [+ 3], 1 + n_parity) each
+        c1 = self.code.encode(bits, terminate=self.terminated)
+        c2 = self.code.encode(bits[..., perm], terminate=self.terminated)
+        coded = jnp.concatenate([c1[..., :N, :], c2[..., :N, 1:]], axis=-1)
+        if self.terminated:
+            # x_K z_K x_K+1 z_K+1 x_K+2 z_K+2, then encoder 2's: 4 rows of 3
+            tails = jnp.concatenate([c1[..., N:, :], c2[..., N:, :]], axis=-2)
+            tails = tails.reshape(bits.shape[:-1] + (self.n_tail_rows, 3))
+            coded = jnp.concatenate([coded, tails], axis=-2)
         if self.puncture is not None:
             mask = pattern_mask(self.n_streams, self.block_len, self.puncture_array)
             coded = (coded * mask).astype(coded.dtype)
@@ -182,6 +234,7 @@ class TurboSpec:
             f"{type(self.interleaver).__name__} N={self.block_len}) "
             f"rate-1/{self.n_streams} {punct}/"
             f"{self.iterations}it{'/early-exit' if self.early_exit else ''}"
+            f"{'' if self.tail == 'none' else '/tail-' + self.tail}"
         )
 
 
@@ -204,26 +257,39 @@ def _iteration_fn(spec: TurboSpec, interpret: Optional[bool]):
     inv = jnp.asarray(spec.interleaver.inverse)
     npar = code.n_parity
     scale = float(spec.extrinsic_scale)
+    N = spec.block_len
+
+    def siso(sys, par, la, tail):
+        """One constituent's posterior LLRs over the N information steps.
+        ``tail`` (B, 3, 2) holds its own [x, z] tail LLRs, or is None for
+        an open trellis; the tail steps get a-priori 0."""
+        coded = jnp.concatenate([sys[..., None], par], axis=-1)
+        if tail is not None:
+            coded = jnp.concatenate([coded, tail], axis=1)
+            la = jnp.pad(la, ((0, 0), (0, tail.shape[1])))
+        llr, _ = bcjr_llr_op(
+            code, coded, la, terminated=tail is not None, interpret=interpret,
+        )
+        return llr[:, :N]
 
     @jax.jit
     def step(llrs, le2, prev_bits, done):
-        lam_sys = llrs[..., 0]
-        lam_p1 = llrs[..., 1:1 + npar]
-        lam_p2 = llrs[..., 1 + npar:]
+        B = llrs.shape[0]
+        lam_sys = llrs[:, :N, 0]
+        lam_p1 = llrs[:, :N, 1:1 + npar]
+        lam_p2 = llrs[:, :N, 1 + npar:]
+        tail1 = tail2 = None
+        if spec.terminated:  # 36.212 rows K..K+3 -> each encoder's 3 [x, z] steps
+            tail1 = llrs[:, N:N + 2].reshape(B, 3, 2)
+            tail2 = llrs[:, N + 2:N + 4].reshape(B, 3, 2)
         # SISO 1 (natural order)
         la1 = le2[:, inv]
-        l1, _ = bcjr_llr_op(
-            code, jnp.concatenate([lam_sys[..., None], lam_p1], axis=-1),
-            la1, terminated=False, interpret=interpret,
-        )
+        l1 = siso(lam_sys, lam_p1, la1, tail1)
         le1 = scale * (l1 - lam_sys - la1)
         # SISO 2 (interleaved order)
         sys2 = lam_sys[:, perm]
         la2 = le1[:, perm]
-        l2, _ = bcjr_llr_op(
-            code, jnp.concatenate([sys2[..., None], lam_p2], axis=-1),
-            la2, terminated=False, interpret=interpret,
-        )
+        l2 = siso(sys2, lam_p2, la2, tail2)
         le2_new = scale * (l2 - sys2 - la2)
         llr_full = l2[:, inv]
         bits = (llr_full < 0).astype(jnp.int32)
@@ -246,8 +312,9 @@ def turbo_decode(
     early_exit: Optional[bool] = None,
     interpret: Optional[bool] = None,
     metrics=None,
+    tracer=None,
 ) -> TurboResult:
-    """Iteratively decode (B, N, n_streams) channel LLRs.
+    """Iteratively decode (B, N + n_tail_rows, n_streams) channel LLRs.
 
     Args:
       llrs: per-bit channel LLRs (spec.channel_llrs of the received block).
@@ -256,49 +323,57 @@ def turbo_decode(
         ``turbo_iterations_total``, ``turbo_llr_agreement`` (per-iteration
         sign-agreement histogram), ``turbo_converged_streams`` and
         ``turbo_early_exits_total``.
+      tracer: optional repro.obs Tracer — records the ``turbo.*`` spans
+        (module docstring).  The bits are the same with or without one.
     """
     iterations = spec.iterations if iterations is None else int(iterations)
     early_exit = spec.early_exit if early_exit is None else bool(early_exit)
-    B, N, ns = llrs.shape
-    if N != spec.block_len or ns != spec.n_streams:
+    B, T, ns = llrs.shape
+    N = spec.block_len
+    if T != spec.n_steps(N) or ns != spec.n_streams:
         raise ValueError(
-            f"expected (B, {spec.block_len}, {spec.n_streams}) LLRs, "
+            f"expected (B, {spec.n_steps(N)}, {spec.n_streams}) LLRs, "
             f"got {llrs.shape}"
         )
-    step = _iteration_fn(spec, interpret)
-    llrs = jnp.asarray(llrs, jnp.float32)
-    le2 = jnp.zeros((B, N), jnp.float32)
-    prev_bits = jnp.full((B, N), -1, jnp.int32)  # never matches: no false freeze
-    done = jnp.zeros((B,), bool)
-    agreements = []
-    bits = llr_full = None
-    n_run = 0
-    for _ in range(iterations):
-        le2, bits, llr_full, done, agree = step(llrs, le2, prev_bits, done)
-        prev_bits = bits
-        n_run += 1
-        agree = float(agree)
-        agreements.append(agree)
-        if metrics is not None:
-            metrics.counter(
-                "turbo_iterations_total", "turbo decode iterations executed"
-            ).inc()
-            metrics.histogram(
-                "turbo_llr_agreement",
-                buckets=(0.5, 0.9, 0.99, 0.999, 1.0),
-                help="per-iteration LLR-sign agreement with the previous iteration",
-            ).observe(agree)
-        if early_exit and bool(done.all()):
+    with span(tracer, "turbo"):
+        step = _iteration_fn(spec, interpret)
+        llrs = jnp.asarray(llrs, jnp.float32)
+        le2 = jnp.zeros((B, N), jnp.float32)
+        prev_bits = jnp.full((B, N), -1, jnp.int32)  # never matches: no false freeze
+        done = jnp.zeros((B,), bool)
+        agreements = []
+        bits = llr_full = None
+        n_run = 0
+        for _ in range(iterations):
+            with span(tracer, "turbo.iteration"):
+                with span(tracer, "turbo.dispatch"):
+                    le2, bits, llr_full, done, agree = step(llrs, le2, prev_bits, done)
+                prev_bits = bits
+                n_run += 1
+                with span(tracer, "turbo.sync"):
+                    agree = float(agree)
+                    all_done = early_exit and bool(done.all())
+            agreements.append(agree)
             if metrics is not None:
                 metrics.counter(
-                    "turbo_early_exits_total",
-                    "decodes stopped before the iteration budget",
+                    "turbo_iterations_total", "turbo decode iterations executed"
                 ).inc()
-            break
-    if metrics is not None:
-        metrics.gauge(
-            "turbo_converged_streams", "streams whose decisions froze"
-        ).set(float(done.sum()))
+                metrics.histogram(
+                    "turbo_llr_agreement",
+                    buckets=(0.5, 0.9, 0.99, 0.999, 1.0),
+                    help="per-iteration LLR-sign agreement with the previous iteration",
+                ).observe(agree)
+            if all_done:
+                if metrics is not None:
+                    metrics.counter(
+                        "turbo_early_exits_total",
+                        "decodes stopped before the iteration budget",
+                    ).inc()
+                break
+        if metrics is not None:
+            metrics.gauge(
+                "turbo_converged_streams", "streams whose decisions froze"
+            ).set(float(done.sum()))
     return TurboResult(
         bits=bits, llr=llr_full, iterations_run=n_run,
         agreement=tuple(agreements), converged=done,

@@ -612,6 +612,45 @@ def test_decode_spans_cover_the_call_and_leave_bits_unchanged(rng, inputs):
     assert len(tr) == n_before
 
 
+def _inside(child, parents):
+    _, t0, d = child
+    return any(a <= t0 and t0 + d <= b for a, b in parents)
+
+
+def test_turbo_spans_nest_and_count_iterations():
+    """decode() of a 36.212 turbo block: ``turbo`` inside
+    ``decode.dispatch``, each ``turbo.iteration`` inside ``turbo`` holding
+    one ``turbo.dispatch`` then one ``turbo.sync``, and the telemetry's
+    ``turbo_iterations_total`` equal to the iterations run."""
+    from repro.siso import RSC_K4_LTE, TurboSpec
+    from repro.siso.interleave import lte_qpp
+
+    spec = TurboSpec(RSC_K4_LTE, lte_qpp(40), iterations=8, tail="36.212")
+    bits = jax.random.bernoulli(jax.random.PRNGKey(7), 0.5, (4, 40)).astype(jnp.int32)
+    coded = spec.encode(bits)
+    rx = 1.0 - 2.0 * coded + 0.8 * jax.random.normal(jax.random.PRNGKey(8), coded.shape)
+    tel = Telemetry.enabled(device_counters=False)
+    runs = [decode(spec, rx, telemetry=tel).diagnostics["iterations"] for _ in range(2)]
+    spans = tel.tracer.spans()
+
+    def of(name):
+        return [s for s in spans if s[0] == name]
+
+    def outer(name):
+        return [(t0, t0 + d) for _, t0, d in of(name)]
+
+    assert len(of("turbo")) == 2
+    assert all(_inside(s, outer("decode.dispatch")) for s in of("turbo"))
+    assert len(of("turbo.iteration")) == sum(runs)
+    assert all(_inside(s, outer("turbo")) for s in of("turbo.iteration"))
+    for name in ("turbo.dispatch", "turbo.sync"):
+        assert len(of(name)) == sum(runs)
+        assert all(_inside(s, outer("turbo.iteration")) for s in of(name))
+    for (_, d0, dd), (_, s0, _) in zip(of("turbo.dispatch"), of("turbo.sync")):
+        assert d0 + dd <= s0  # the sync follows its iteration's dispatch
+    assert tel.metrics.snapshot()["turbo_iterations_total"] == sum(runs)
+
+
 @pytest.mark.parametrize("sharded", [False, True])
 def test_submit_chunk_spans_its_phases(request, rng, sharded):
     """submit_chunk stages on the host: its spans cover the call, no device

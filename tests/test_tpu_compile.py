@@ -142,6 +142,26 @@ def test_bcjr_beta_llr_compiles(chip, terminated):
     assert out.shape == (512, 1, LANES)
 
 
+def test_lte_turbo_iteration_compiles(chip):
+    """One 36.212 turbo iteration (both SISO passes with their tails, the
+    interleaver gathers, the freeze) at the lte_turbo.cb6144 shape: B=256
+    code blocks of K=6144, (B, K + 4, 3) LLRs in."""
+    from repro.siso import TurboSpec
+    from repro.siso.interleave import lte_qpp
+    from repro.siso.turbo import _iteration_fn
+
+    spec = TurboSpec(RSC_K4_LTE, lte_qpp(6144), iterations=8, tail="36.212")
+    Bt, K = 256, 6144
+    compiled = _compile_has_kernel(
+        _iteration_fn(spec, False), _sds(chip, (Bt, K + 4, 3)), _sds(chip, (Bt, K)),
+        _sds(chip, (Bt, K), jnp.int32), _sds(chip, (Bt,), jnp.bool_),
+    )
+    assert compiled.as_text().count("tpu_custom_call") >= 4  # alpha, beta + LLR, twice
+    # the alphas' HBM round trip, (K + 3, 8, 256) float32 per pass, bounds
+    # the temporaries: some hundreds of MB, well inside 16 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
 def test_minplus_matmul_compiles(chip):
     S = CODE_K7_NASA.n_states
     # the (S, S) state-map composition at B=128 maps per launch, padded the
