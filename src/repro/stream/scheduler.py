@@ -351,6 +351,7 @@ class StreamScheduler:
         #: which writes them first
         self._staged: List[List[np.ndarray]] = [[] for _ in range(self.n_shards)]
         self._write = _arena_writer(mesh, mesh_axis)
+        self._compact_arena = _arena_compactor(mesh, mesh_axis)
         self._compact_ratio = 4
         self._compact_floor = 4096
         if mesh is not None:
@@ -359,6 +360,7 @@ class StreamScheduler:
 
             self._arena_sharding = NamedSharding(mesh, P(mesh_axis, None, None))
             self._start_sharding = NamedSharding(mesh, P(mesh_axis))
+            self._index_sharding = NamedSharding(mesh, P(mesh_axis, None))
             self._counter_sharding = NamedSharding(mesh, P(mesh_axis))
             self.state = _w.shard_stream_state(mesh, mesh_axis, self.state)
             self._arena = jax.device_put(self._arena, self._arena_sharding)
@@ -373,6 +375,7 @@ class StreamScheduler:
         else:
             self._arena_sharding = None
             self._start_sharding = None
+            self._index_sharding = None
             self._counter_sharding = None
             self._sharded_step = None
             self._step_fn = _w.jitted_stream_step(
@@ -1084,8 +1087,7 @@ class StreamScheduler:
         return ticks
 
     def _pin_arena(self) -> None:
-        """Re-assert the per-shard arena placement after an eager mutation
-        (growth, compaction)."""
+        """Re-assert the per-shard arena placement after its eager growth."""
         if self._arena_sharding is not None:
             self._arena = jax.device_put(self._arena, self._arena_sharding)
 
@@ -1191,28 +1193,22 @@ class StreamScheduler:
             self._compact()
 
     def _compact(self) -> None:
+        """Rebuild every shard's slab as the zero prefix, then each admitted
+        stream's unconsumed rows in order, then zeros to the capacity: one
+        jitted row gather (shard-local on a mesh) by a host-built source
+        index whose shape depends only on the capacity, so it compiles once
+        per capacity.  Zero rows read row 0."""
         arena = self._read_arena()
-        by_shard: Dict[int, List[_Stream]] = {}
+        src = np.zeros(arena.shape[:2], np.int32)
+        cursor = [self.chunk] * self.n_shards
         for st in self.active.values():
-            by_shard.setdefault(st.shard, []).append(st)
-        cap = arena.shape[1]
-        slabs = []
-        for shard in range(self.n_shards):
-            parts = [jnp.zeros((self.chunk, self._width), dtype=jnp.float32)]
-            cursor = self.chunk
-            for st in by_shard.get(shard, ()):
-                n = st.available
-                if n:
-                    parts.append(
-                        jnp.take(arena[shard], jnp.asarray(st.rows), axis=0)
-                    )
-                st.rows = np.arange(cursor, cursor + n, dtype=np.int32)
-                cursor += n
-            parts.append(jnp.zeros((max(cap - cursor, 0), self._width), jnp.float32))
-            slabs.append(jnp.concatenate(parts, axis=0))
-            self._arena_len[shard] = cursor
-        self._arena = jnp.stack(slabs, axis=0)
-        self._pin_arena()
+            at, n = cursor[st.shard], st.available
+            src[st.shard, at : at + n] = st.rows
+            st.rows = np.arange(at, at + n, dtype=np.int32)
+            cursor[st.shard] = at + n
+        src = jax.device_put(src, self._index_sharding)
+        self._arena = self._compact_arena(arena, src)
+        self._arena_len = cursor
         self.stats.arena_compactions += 1
 
     def _collect(self, st: _Stream) -> np.ndarray:
@@ -1353,13 +1349,30 @@ class StreamScheduler:
             self.alloc.release(slot)  # state is re-initialized at next claim
 
 
+def _per_shard(fn, mesh, axis: str, ranks: Sequence[int]):
+    """``fn`` over the (n_shards, cap, W) arena and per-shard arguments of
+    the given ranks, jitted with the arena donated.  On a mesh each chip
+    runs ``fn`` on its own slab and argument rows under shard_map, so
+    nothing crosses chips; without one, the one shard is the whole arena."""
+    if mesh is None:
+        return jax.jit(fn, donate_argnums=0)
+    from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.mesh import shard_map
+
+    specs = tuple(P(axis, *(None,) * (rank - 1)) for rank in ranks)
+    return jax.jit(
+        shard_map(fn, mesh=mesh, in_specs=specs, out_specs=specs[0]),
+        donate_argnums=0,
+    )
+
+
 @functools.lru_cache(maxsize=None)
 def _arena_writer(mesh, axis: str):
     """``write(arena, start, block)``: writes shard ``s``'s rows
     ``block[s]`` into its slab of the (n_shards, cap, W) arena from row
-    ``start[s]`` on, in place (the arena is donated).  On a mesh each chip
-    writes its own slab under shard_map, so nothing crosses chips.  One
-    jitted callable per (mesh, axis), shared by every scheduler.
+    ``start[s]`` on, in place (the arena is donated), shard-local on a
+    mesh.  One jitted callable per (mesh, axis), shared by every scheduler.
 
     A dynamic_update_slice, not a row scatter: on the TPU a (cap, W) slab
     with W of 2 keeps its rows along the lanes, and a scatter of rows there
@@ -1369,17 +1382,21 @@ def _arena_writer(mesh, axis: str):
         # one shard's slab: (1, cap, W); a scheduler without a mesh has one
         return jax.lax.dynamic_update_slice(arena, block, (0, start[0], 0))
 
-    if mesh is None:
-        return jax.jit(write, donate_argnums=0)
-    from jax.sharding import PartitionSpec as P
+    return _per_shard(write, mesh, axis, (3, 1, 3))
 
-    from repro.parallel.mesh import shard_map
 
-    return jax.jit(
-        shard_map(
-            write, mesh=mesh,
-            in_specs=(P(axis, None, None), P(axis), P(axis, None, None)),
-            out_specs=P(axis, None, None),
-        ),
-        donate_argnums=0,
-    )
+@functools.lru_cache(maxsize=None)
+def _arena_compactor(mesh, axis: str):
+    """``compact(arena, src)``: the (n_shards, cap, W) arena rebuilt into
+    its own (donated) buffer, each shard's slab row ``r`` taken from its row
+    ``src[s, r]``, shard-local on a mesh.  One jitted callable per (mesh,
+    axis), shared by every scheduler.
+
+    On the TPU the (cap, W) slab keeps its rows along the lanes, so the
+    gather goes through a lane-padded temporary of about 1 KB per row."""
+
+    def compact(arena, src):
+        # one shard's slab: (1, cap, W); a scheduler without a mesh has one
+        return jnp.take(arena[0], src[0], axis=0, mode="clip")[None]
+
+    return _per_shard(compact, mesh, axis, (3, 2))

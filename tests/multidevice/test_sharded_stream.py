@@ -296,3 +296,26 @@ def test_sharded_arena_write_holds_no_collective(mesh81):
                "reduce-scatter"):
         assert op not in hlo, op
     assert "input_output_alias" in hlo
+
+
+def test_sharded_arena_compaction_holds_no_collective(mesh81):
+    """Compiled for the mesh, the compaction gathers each shard's slab by
+    its own index row on its own device (no collective) and rebuilds the
+    donated arena in its own buffer."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.stream.scheduler import _arena_compactor
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh81, spec))
+
+    compiled = _arena_compactor(mesh81, "data").lower(
+        sds((8, 4096, 2), jnp.float32, P("data", None, None)),
+        sds((8, 4096), jnp.int32, P("data", None)),
+    ).compile()
+    hlo = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all", "collective-permute",
+               "reduce-scatter"):
+        assert op not in hlo, op
+    assert "input_output_alias" in hlo

@@ -956,3 +956,130 @@ def test_submit_chunk_copies_the_callers_rows(rng):
         buf[:] = np.nan  # the next receive overwrites the buffer
         sched.step()
     np.testing.assert_array_equal(sched.run()["s"][0], np.asarray(ref[0]))
+
+
+# --------------------------------------------------------------------------- #
+# (l) jitted arena compaction                                                  #
+# --------------------------------------------------------------------------- #
+
+
+def _reference_compaction(arena, sched):
+    """The construction the jitted compaction replaced, in NumPy: per shard
+    the zero prefix, each admitted stream's unconsumed rows in stream order,
+    zeros to the capacity.  Returns the arena, each stream's new row map and
+    each shard's used rows."""
+    want = np.zeros_like(arena)
+    cursor = [sched.chunk] * arena.shape[0]
+    rows = {}
+    for st in sched.active.values():
+        c, n = cursor[st.shard], st.available
+        want[st.shard, c : c + n] = arena[st.shard, st.rows]
+        rows[st.stream_id] = np.arange(c, c + n)
+        cursor[st.shard] = c + n
+    return want, rows, cursor
+
+
+def _random_compaction_run(seed, whole_chunks, check):
+    """Staggered streams (more than slots, so some are admitted mid-run) fed
+    random pieces, ragged or whole chunks, through a scheduler and, in
+    lockstep, through one that never compacts; ``check(sched)`` runs before
+    a random half of the ticks.  Returns the scheduler that compacted, after
+    both drained to the same bits."""
+    from repro.stream import StreamBusy
+
+    rs = np.random.default_rng(seed)
+    code, chunk = CODE_K3_STD, 16
+    n_slots = int(rs.integers(2, 5))
+    scheds = [StreamScheduler(code, n_slots=n_slots, chunk=chunk, depth=30,
+                              backend="scan") for _ in range(2)]
+    sched, plain = scheds
+    sched._compact_floor = plain._compact_floor = 1 << 30  # check() compacts
+    tables = {}
+    for i in range(n_slots + int(rs.integers(1, 4))):
+        steps = int(rs.integers(3, 12)) * chunk
+        _, bm = _noisy_bm(code, jax.random.PRNGKey(1000 * seed + i), 1, steps - 2, 0.02)
+        tables[f"s{i}"] = np.asarray(bm[0])
+    cursor = dict.fromkeys(tables, 0)
+    opened = []
+    tick = 0
+    while any(s.pending_work() for s in scheds) or len(opened) < len(tables):
+        if len(opened) < len(tables) and rs.random() < 0.6:
+            sid = f"s{len(opened)}"
+            opened.append(sid)
+            for s in scheds:
+                s.open_stream(sid)
+        for sid in opened:
+            table, c = tables[sid], cursor[sid]
+            if c >= len(table) or rs.random() < 0.3:
+                continue
+            size = chunk * int(rs.integers(1, 3)) if whole_chunks else int(rs.integers(1, 40))
+            piece = table[c : c + min(size, sched.credit(sid))]
+            if not len(piece):
+                continue
+            close = c + len(piece) == len(table)
+            try:
+                for s in scheds:
+                    s.submit_chunk(sid, piece, close=close)
+            except StreamBusy:
+                pytest.fail("both schedulers grant the same credit")
+            cursor[sid] = c + len(piece)
+        if rs.random() < 0.5:
+            check(sched)
+        for s in scheds:
+            s.step()
+        tick += 1
+        assert tick < 500
+    assert set(sched.results) == set(plain.results) == set(tables)
+    for sid in tables:
+        np.testing.assert_array_equal(sched.results[sid][0], plain.results[sid][0])
+        assert sched.results[sid][1] == plain.results[sid][1]
+    return sched
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("layout", ["ragged", "whole_chunks"])
+def test_jitted_compaction_matches_reference_construction(layout, seed):
+    """Across random layouts (stream counts, ragged or whole-chunk pieces,
+    streams admitted mid-run), the jitted compaction leaves the arena bit
+    for bit as the per-stream construction did, moves every row map to its
+    new rows and sets each shard's used rows; decodes are unchanged."""
+    checked = []
+
+    def check(sched):
+        arena = np.asarray(sched._read_arena())
+        want, rows, cursor = _reference_compaction(arena, sched)
+        sched._compact()
+        got = np.asarray(sched._arena)
+        np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+        for st in sched.active.values():
+            np.testing.assert_array_equal(st.rows, rows[st.stream_id])
+        assert sched._arena_len == cursor
+        _assert_arena_integrity(sched)
+        checked.append(sum(st.available for st in sched.active.values()))
+
+    sched = _random_compaction_run(seed, layout == "whole_chunks", check)
+    assert sched.stats.arena_compactions == len(checked) > 2
+    assert len(set(checked)) > 1  # the live rows differ between compactions
+
+
+def test_compaction_compiles_once_per_capacity():
+    """Compactions that find different live-row counts at one capacity run
+    one compiled program: the jitted callable's cache holds one entry per
+    capacity, however many compactions ran."""
+    from repro.stream.scheduler import _arena_compactor
+
+    _arena_compactor.cache_clear()  # a fresh callable, with an empty cache
+    live_by_cap = {}
+    compactors = []
+
+    def check(sched):
+        if not compactors:
+            compactors.append(sched._compact_arena)
+        live_by_cap.setdefault(sched._read_arena().shape[1], set()).add(
+            sum(st.available for st in sched.active.values())
+        )
+        sched._compact()
+
+    _random_compaction_run(7, False, check)
+    assert max(len(v) for v in live_by_cap.values()) > 1
+    assert compactors[0]._cache_size() == len(live_by_cap)
