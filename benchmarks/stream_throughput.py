@@ -9,9 +9,9 @@ Five modes:
 
 * default: drives the continuous-batching StreamScheduler with >= 64
   concurrent decode sessions multiplexed through ONE jitted chunked Pallas
-  call per tick — comparing the unpacked ``fused`` hot loop against the
-  ``fused_packed`` pipeline (bit-packed survivor ring + on-device traceback,
-  device-resident input arena) — and reports sustained decoded bits/s
+  call per tick — the ``fused_packed`` pipeline (bit-packed survivor ring +
+  on-device traceback, device-resident input arena), plus ``--backend``'s
+  loop when that is ``scan`` — and reports sustained decoded bits/s
   against the full-block fused decoder on the same workload, re-checking the
   two correctness gates the streaming path promises (depth >= T bit-exact;
   depth = 5K within 1e-3 BER of the block decoder).
@@ -58,7 +58,7 @@ Five modes:
   ``stream.resilience`` of BENCH_viterbi.json (schema v6).
 
   PYTHONPATH=src python benchmarks/stream_throughput.py [--sessions 64]
-      [--steps 512] [--chunk 64] [--flip 0.02] [--backend fused]
+      [--steps 512] [--chunk 64] [--flip 0.02] [--backend fused_packed]
   PYTHONPATH=src python benchmarks/stream_throughput.py --smoke --shards 1
   PYTHONPATH=src python benchmarks/stream_throughput.py --smoke --shards 8
   PYTHONPATH=src python benchmarks/stream_throughput.py --smoke --online
@@ -671,7 +671,7 @@ def run_backend_comparison(args) -> None:
     key = jax.random.PRNGKey(0)
     steps = args.steps or 512
     sessions = args.sessions or STREAM.n_slots
-    backend = args.backend or "fused"
+    backend = args.backend or "fused_packed"
     info_bits = steps - spec.n_flush
     info, bm = make_workload(spec, key, sessions, info_bits, args.flip)
     ref_bits, _ = viterbi_decode(code, bm)
@@ -768,7 +768,7 @@ def main():
     ap.add_argument("--chunk", type=int, default=STREAM.chunk)
     ap.add_argument("--flip", type=float, default=0.02)
     ap.add_argument("--backend", default=None,
-                    choices=("fused", "fused_packed", "scan"))
+                    choices=("fused_packed", "scan"))
     ap.add_argument("--shards", type=int, default=0,
                     help="run the sharded-scheduler scaling mode on an N-way "
                          "data mesh (weak-scaled: --slots-per-shard per device)")

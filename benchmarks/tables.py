@@ -27,7 +27,7 @@ from benchmarks import paper_model as pm
 from repro.core import CODE_K3_STD, bsc, encode, hard_branch_metrics
 from repro.core.acs import acs_step, acs_step_unfused
 from repro.core.viterbi import viterbi_decode
-from repro.kernels.ops import viterbi_decode_fused
+from repro.kernels.ops import viterbi_decode_packed
 
 
 def _assert_close(got: Dict, want: Dict, tol=1.0):
@@ -90,7 +90,7 @@ def tpu_analogue(batch=512, info_bits=64, seed=0) -> Dict[str, float]:
         return viterbi_decode(code, bm)[1]
 
     def dec_fused_kernel(bm):
-        return viterbi_decode_fused(code, bm)[1]
+        return viterbi_decode_packed(code, bm)[1]
 
     t_unfused = _bench(dec_unfused, bm)
     t_ref = _bench(dec_fused_ref, bm)

@@ -20,7 +20,7 @@ from repro.core import (
     viterbi_decode,
     viterbi_decode_parallel,
 )
-from repro.kernels import viterbi_decode_fused
+from repro.kernels import viterbi_decode_packed
 
 
 def main():
@@ -37,7 +37,7 @@ def main():
     # --- 3: decode (fused kernel == reference) ---------------------------- #
     bm = hard_branch_metrics(code, received)
     dec_ref, metric_ref = viterbi_decode(code, bm)
-    dec_fused, metric_fused = viterbi_decode_fused(code, bm)
+    dec_fused, metric_fused = viterbi_decode_packed(code, bm)
     assert (dec_ref == dec_fused).all() and jnp.allclose(metric_ref, metric_fused)
     ber = float((dec_fused[:, :64] != bits).mean())
     print(f"fused Texpand decode: BER={ber:.4f}  "

@@ -4,8 +4,8 @@ Every decoder in the registry maps to exactly ONE catalog entry that knows
 how to build a traceable callable for its hot loop plus the :class:`Contract`
 that loop must satisfy:
 
-  * the block backends (sequential / parallel / fused / fused_packed /
-    tiled / bcjr) trace their registry entry directly on a small abstract
+  * the block backends (sequential / parallel / fused_packed / tiled /
+    bcjr) trace their registry entry directly on a small abstract
     workload;
   * the scheduler-driven backends (streaming, sharded_stream) are Python
     orchestration around a jitted tick — the tick body IS the hot path, so
@@ -117,30 +117,34 @@ def _seqparallel_builder():
 
 def _stream_tick_builder(chunk: int = 32):
     """The single-device tick body behind sessions and the scheduler
-    (streaming backend): one stream_step over carried state."""
+    (streaming backend), as the stream cells run it: one packed
+    ``stream_step`` on raw received features with folded metric weights,
+    over a uint32 (R/32, B, S) survivor ring."""
 
     def build():
-        from repro.kernels.common import resolve_interpret
+        from repro.kernels.common import PACK_BITS, resolve_interpret
         from repro.stream import window as w
 
         spec = _conv_spec()
         code = spec.code
         interpret = resolve_interpret(None)
-        B, depth = 4, w.default_depth(code)
-        R = depth + chunk
+        _, depth, plan, weights = w.resolve_stream_backend(
+            spec, chunk, w.default_depth(code), w.PACKED_BACKEND, "received"
+        )
+        B, R = 4, depth + chunk
         pm = jax.ShapeDtypeStruct((B, code.n_states), jnp.float32)
-        ring = jax.ShapeDtypeStruct((R, B, code.n_states), jnp.int32)
-        chunk_bm = jax.ShapeDtypeStruct((B, chunk, 2 ** code.n_out), jnp.float32)
+        ring = jax.ShapeDtypeStruct((R // PACK_BITS, B, code.n_states), jnp.uint32)
+        feats = jax.ShapeDtypeStruct((B, chunk, plan.n_features), jnp.float32)
         active = jax.ShapeDtypeStruct((B,), jnp.bool_)
 
-        def fn(pm, ring, chunk_bm, active):
+        def fn(pm, ring, feats, active):
             state, bits, delta = w.stream_step(
-                code, w.StreamState(pm=pm, ring=ring), chunk_bm,
-                active=active, backend="fused", interpret=interpret,
+                code, w.StreamState(pm=pm, ring=ring), feats, weights=weights,
+                active=active, backend=w.PACKED_BACKEND, interpret=interpret,
             )
             return state.pm, state.ring, bits, delta
 
-        return fn, (pm, ring, chunk_bm, active)
+        return fn, (pm, ring, feats, active)
 
     return build
 
@@ -249,13 +253,6 @@ def hot_path_catalog() -> Tuple[HotPath, ...]:
             summary="(min,+) associative-scan block decode",
         ),
         HotPath(
-            name="fused", backend="fused",
-            contract=_contract("fused", max_outputs=_BLOCK_OUTPUTS,
-                               **comms_free),
-            build=_block_builder("fused"),
-            summary="Pallas Texpand scan block decode",
-        ),
-        HotPath(
             name="fused_packed", backend="fused_packed",
             contract=_contract("fused_packed", max_outputs=_BLOCK_OUTPUTS,
                                **comms_free),
@@ -285,7 +282,7 @@ def hot_path_catalog() -> Tuple[HotPath, ...]:
             contract=_contract("stream_tick", max_outputs=_TICK_OUTPUTS,
                                **comms_free),
             build=_stream_tick_builder(),
-            summary="single-device session/scheduler tick body",
+            summary="single-device packed tick on received symbols",
         ),
         HotPath(
             name="sharded_stream_tick", backend="sharded_stream",

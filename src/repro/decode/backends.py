@@ -26,21 +26,6 @@ def _result(spec: CodecSpec, bits: jnp.ndarray, metric: jnp.ndarray, **diag) -> 
     return DecodeResult(bits=bits, path_metric=metric, spec=spec, diagnostics=diag)
 
 
-@register_decoder(
-    "fused",
-    capabilities=BackendCapabilities(family="conv", max_states=FUSED_MAX_STATES),
-)
-def decode_fused(spec: CodecSpec, bm_tables, *, ctx: DecodeContext) -> DecodeResult:
-    """Pallas Texpand scan with VMEM-resident path metrics (the paper's
-    custom instruction) — the default block decoder."""
-    from repro.kernels.ops import viterbi_decode_fused
-
-    bits, metric = viterbi_decode_fused(
-        spec.code, bm_tables, terminated=spec.terminated, interpret=ctx.interpret
-    )
-    return _result(spec, bits, metric, backend="fused")
-
-
 def _fused_packed_from_received(
     spec: CodecSpec, received, *, ctx: DecodeContext
 ) -> DecodeResult:
@@ -64,9 +49,10 @@ def _fused_packed_from_received(
     from_received=_fused_packed_from_received,
 )
 def decode_fused_packed(spec: CodecSpec, bm_tables, *, ctx: DecodeContext) -> DecodeResult:
-    """Memory-lean Pallas pipeline: VMEM-resident scan with bit-packed
-    survivors (32× smaller than ``fused``'s) + on-device packed traceback;
-    given raw symbols it also computes branch metrics in-kernel."""
+    """Pallas pipeline — the default block decoder: VMEM-resident scan
+    (the paper's custom instruction) with bit-packed survivors + on-device
+    packed traceback; given raw symbols it also computes branch metrics
+    in-kernel."""
     from repro.kernels.ops import viterbi_decode_packed
 
     bits, metric = viterbi_decode_packed(
@@ -206,9 +192,8 @@ def decode_sharded_stream(spec: CodecSpec, bm_tables, *, ctx: DecodeContext) -> 
     B, T = bm_tables.shape[:2]
     depth = ctx.stream_depth if ctx.stream_depth is not None else default_depth(spec.code)
     n_slots = -(-B // n) * n  # slot table must divide over the shards
-    backend = "fused_packed" if ctx.chunk % 32 == 0 else "fused"
     sched = StreamScheduler(
-        spec, n_slots=n_slots, chunk=ctx.chunk, depth=depth, backend=backend,
+        spec, n_slots=n_slots, chunk=ctx.chunk, depth=depth,
         interpret=ctx.interpret, mesh=ctx.mesh, mesh_axis=ctx.batch_axis,
     )
     for i in range(B):
@@ -219,7 +204,6 @@ def decode_sharded_stream(spec: CodecSpec, bm_tables, *, ctx: DecodeContext) -> 
     return _result(
         spec, bits, metric, backend="sharded_stream", shards=n,
         batch_axis=ctx.batch_axis, n_slots=n_slots, depth=depth,
-        hot_loop=backend,
     )
 
 

@@ -7,8 +7,8 @@ Every decoder in the repo implements ONE normalized signature
 
 and registers itself with a capability record:
 
-    @register_decoder("fused", capabilities=BackendCapabilities(...))
-    def _fused(spec, bm_tables, *, ctx): ...
+    @register_decoder("fused_packed", capabilities=BackendCapabilities(...))
+    def decode_fused_packed(spec, bm_tables, *, ctx): ...
 
 The registry replaces the string ``if/elif`` dispatch chain of the old
 serving head: adding a backend (a ROADMAP item like sharded streaming or

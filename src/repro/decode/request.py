@@ -31,7 +31,8 @@ class DecodeContext:
       mesh_axis: mesh axis name the sequence is sharded over.
       batch_axis: mesh axis name batch/slot-parallel backends shard over
         (the sharded stream scheduler's slot table spans this axis).
-      chunk: chunk length for chunked backends (parallel scan, streaming).
+      chunk: chunk length for chunked backends (parallel scan, streaming);
+        a multiple of 32 for the streaming backends' packed survivor ring.
       stream_depth: truncated-traceback depth for the streaming backend
         (None = the textbook 5*K).
       streaming: a live session context — the caller consumes bits a fixed

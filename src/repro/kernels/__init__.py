@@ -1,9 +1,9 @@
 """Pallas TPU kernels for the paper's compute hot-spot (trellis ACS).
 
 texpand.py      — the paper's custom instruction: one fused ACS step
-viterbi_scan.py — full-T / chunked scan with VMEM-resident path metrics, one
-                  parameterized body: table or in-kernel branch metrics,
-                  unpacked or bit-packed survivors
+viterbi_scan.py — full-T / chunked / windowed scan with VMEM-resident path
+                  metrics and bit-packed survivors, one entry: table or
+                  in-kernel branch metrics
 survivors.py    — survivor memory unit: 32-per-uint32 pack/unpack helpers +
                   the Pallas traceback kernel over packed words
 metrics.py      — affine in-kernel branch-metric plans (hard/soft/punctured)
@@ -25,15 +25,10 @@ from repro.kernels.minplus import (
 from repro.kernels.ops import (
     minplus_matmul_op,
     texpand_op,
-    viterbi_decode_fused,
     viterbi_decode_fused_packed,
     viterbi_decode_packed,
     viterbi_decode_tiled_fused,
     viterbi_decode_tiled_op,
-    viterbi_forward_chunk_op,
-    viterbi_forward_fused_op,
-    viterbi_forward_op,
-    viterbi_forward_packed_op,
     viterbi_forward_weighted_op,
     viterbi_traceback_op,
 )
@@ -63,15 +58,10 @@ __all__ = [
     "traceback_packed_window",
     "truncation_depth",
     "unpack_survivors",
-    "viterbi_decode_fused",
     "viterbi_decode_fused_packed",
     "viterbi_decode_packed",
     "viterbi_decode_tiled_fused",
     "viterbi_decode_tiled_op",
-    "viterbi_forward_chunk_op",
-    "viterbi_forward_fused_op",
-    "viterbi_forward_op",
-    "viterbi_forward_packed_op",
     "viterbi_forward_weighted_op",
     "viterbi_traceback_op",
 ]

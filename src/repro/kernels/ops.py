@@ -2,16 +2,18 @@
 
 Handle layout conversion ((B, S) user layout <-> (S, B) kernel layout),
 lane/sublane padding, interpret-mode selection (CPU container -> interpret;
-real TPU -> compiled), and compose the full fused decoders:
+real TPU -> compiled), and compose the full fused decoders.  Every Viterbi
+decoder here runs the one packed-survivor forward entry
+(kernels/viterbi_scan.viterbi_scan) and the Pallas traceback over its words:
 
-  classic      viterbi_decode_fused: bm tables in, unpacked (T, S, B) int32
-               survivors out, XLA scan-of-gathers traceback.
-  packed       viterbi_decode_packed: bm tables in, 32×-smaller packed
-               survivors out, Pallas traceback kernel — the survivors never
-               exist unpacked in HBM.
+  packed       viterbi_decode_packed: bm tables in, 32-per-word packed
+               survivors, Pallas traceback — the survivors never exist
+               unpacked in HBM.
   fused+packed viterbi_decode_fused_packed: raw received symbols in, branch
                metrics computed in-kernel (kernels/metrics.py), packed
                survivors, Pallas traceback — the full memory-lean hot path.
+  tiled        viterbi_decode_tiled_op / _fused: P time-tiles of one long
+               block on the lane axis of one windowed launch.
 """
 from __future__ import annotations
 
@@ -23,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.trellis import NEG_UNREACHABLE, ConvCode
-from repro.core.viterbi import _traceback
 from repro.kernels import bcjr as _bcjr
 from repro.kernels import minplus as _minplus
 from repro.kernels import survivors as _surv
@@ -53,55 +54,6 @@ def texpand_op(
     return new_pm[:, :B].T, bp[:, :B].T
 
 
-def viterbi_forward_op(
-    code: ConvCode,
-    bm_tables: jnp.ndarray,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full fused forward pass.  bm_tables: (B, T, M).
-
-    Returns final_pm (B, S) and backpointers (T, B, S) (traceback layout).
-    """
-    B, T, M = bm_tables.shape
-    bm_k = bm_tables.transpose(1, 2, 0)  # (T, M, B)
-    block_b = lane_block(B)
-    bm_k, _ = pad_axis_to(bm_k, 2, block_b, 0.0)
-    final_pm, bps = _vscan.viterbi_scan(
-        code, bm_k.astype(jnp.float32), block_b, interpret
-    )
-    return final_pm[:, :B].T, bps[:, :, :B].transpose(0, 2, 1)
-
-
-def viterbi_forward_chunk_op(
-    code: ConvCode,
-    pm: jnp.ndarray,
-    bm_chunk: jnp.ndarray,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Chunked fused forward pass with carried path metrics — the streaming
-    entry point.  The caller owns the cross-chunk state (path metrics and a
-    traceback ring buffer, see stream/session.py); this op advances the path
-    metrics C steps through the VMEM-resident Pallas scan.
-
-    Args:
-      pm: (B, S) float32 path metrics entering the chunk.
-      bm_chunk: (B, C, M) branch-metric tables for the chunk.
-    Returns:
-      new_pm: (B, S) path metrics after the chunk.
-      bps: (C, B, S) int32 backpointer parities (traceback layout).
-    """
-    B, C, M = bm_chunk.shape
-    pm_k = pm.T  # (S, B)
-    bm_k = bm_chunk.transpose(1, 2, 0)  # (C, M, B)
-    block_b = lane_block(B)
-    pm_k, _ = pad_axis_to(pm_k, 1, block_b, NEG_UNREACHABLE)
-    bm_k, _ = pad_axis_to(bm_k, 2, block_b, 0.0)
-    new_pm, bps = _vscan.viterbi_scan_carry(
-        code, pm_k.astype(jnp.float32), bm_k.astype(jnp.float32), block_b, interpret
-    )
-    return new_pm[:, :B].T, bps[:, :, :B].transpose(0, 2, 1)
-
-
 # --------------------------------------------------------------------------- #
 # Packed-survivor pipeline: forward (+ optional in-kernel metrics), traceback. #
 # --------------------------------------------------------------------------- #
@@ -123,47 +75,14 @@ def viterbi_forward_weighted_op(
     data = data_btf.transpose(1, 2, 0).astype(jnp.float32)  # (T, F, B)
     block_b = lane_block(B)
     data, _ = pad_axis_to(data, 2, block_b, 0.0)
-    if pm0 is None:
-        final_pm, packed = _vscan.viterbi_scan_packed(
-            code, data, b0, b1, rb, block_b, interpret
-        )
-    else:
+    pm_k = None
+    if pm0 is not None:
         pm_k, _ = pad_axis_to(pm0.T, 1, block_b, NEG_UNREACHABLE)
-        final_pm, packed = _vscan.viterbi_scan_packed_carry(
-            code, pm_k.astype(jnp.float32), data, b0, b1, rb, block_b, interpret
-        )
-    return final_pm[:, :B].T, packed[:, :, :B].transpose(0, 2, 1)
-
-
-def viterbi_forward_packed_op(
-    code: ConvCode,
-    bm_tables: jnp.ndarray,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Forward pass with bit-packed survivors from precomputed bm tables.
-
-    bm_tables: (B, T, M) -> final_pm (B, S), packed (ceil(T/32), B, S) uint32
-    — the survivor tensor is 32× smaller than viterbi_forward_op's.
-    """
-    return viterbi_forward_weighted_op(
-        code, None, bm_tables, _vscan.table_weights(code), interpret
+        pm_k = pm_k.astype(jnp.float32)
+    final_pm, packed = _vscan.viterbi_scan(
+        code, data, b0, b1, rb, block_b, interpret, pm0=pm_k
     )
-
-
-def viterbi_forward_fused_op(
-    plan: FusedMetricPlan,
-    received: jnp.ndarray,
-    t0: int = 0,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Forward pass with **in-kernel branch metrics** + packed survivors.
-
-    received: (B, T, n_out) raw channel symbols (hard bits or soft values);
-    the kernel streams these F-wide features instead of an M-wide bm table.
-    Returns final_pm (B, S), packed (ceil(T/32), B, S) uint32.
-    """
-    feats = plan.features(received, t0)
-    return viterbi_forward_weighted_op(plan.code, None, feats, plan.folded(), interpret)
+    return final_pm[:, :B].T, packed[:, :, :B].transpose(0, 2, 1)
 
 
 def viterbi_traceback_op(
@@ -200,37 +119,24 @@ def _frontier(
     return final_state, metric
 
 
-def viterbi_decode_fused(
-    code: ConvCode,
-    bm_tables: jnp.ndarray,
-    terminated: bool = True,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Drop-in fused replacement for core.viterbi.viterbi_decode.
-
-    bm_tables: (B, T, M) -> (bits (B, T), metric (B,)).
-    """
-    interpret = resolve_interpret(interpret)  # pinned per decode
-    final_pm, bps = viterbi_forward_op(code, bm_tables, interpret)
-    final_state, metric = _frontier(final_pm, terminated)
-    bits, _ = _traceback(code, bps, final_state)
-    return bits, metric
-
-
 def viterbi_decode_packed(
     code: ConvCode,
     bm_tables: jnp.ndarray,
     terminated: bool = True,
     interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused decode with packed survivors + on-device traceback (bm tables
-    in).  Bit-exact vs viterbi_decode_fused; survivor HBM footprint is 32×
-    smaller and the traceback never leaves the device."""
+    """Drop-in Pallas replacement for core.viterbi.viterbi_decode: packed
+    survivors + on-device traceback (bm tables in).
+
+    bm_tables: (B, T, M) -> (bits (B, T), metric (B,)).
+    """
     T = bm_tables.shape[1]
     # resolve interpret ONCE so the forward scan and the traceback kernel of
     # this decode can never auto-detect onto different code paths
     interpret = resolve_interpret(interpret)
-    final_pm, packed = viterbi_forward_packed_op(code, bm_tables, interpret)
+    final_pm, packed = viterbi_forward_weighted_op(
+        code, None, bm_tables, _vscan.table_weights(code), interpret
+    )
     final_state, metric = _frontier(final_pm, terminated)
     bits = viterbi_traceback_op(code, packed, final_state, T, interpret)
     return bits, metric
@@ -249,7 +155,9 @@ def viterbi_decode_fused_packed(
     """
     T = received.shape[1]
     interpret = resolve_interpret(interpret)  # pinned per decode
-    final_pm, packed = viterbi_forward_fused_op(plan, received, 0, interpret)
+    final_pm, packed = viterbi_forward_weighted_op(
+        plan.code, None, plan.features(received, 0), plan.folded(), interpret
+    )
     final_state, metric = _frontier(final_pm, terminated)
     bits = viterbi_traceback_op(plan.code, packed, final_state, T, interpret)
     return bits, metric
@@ -324,8 +232,8 @@ def _tiled_weighted_decode(
         p1, _ = pad_axis_to(jnp.tile(eye, (1, B * P)), 1, blk1, NEG_UNREACHABLE)
         l1, _ = pad_axis_to(_tile_lane_row(lo_np, B, S), 1, blk1, 0)
         h1, _ = pad_axis_to(_tile_lane_row(hi_np, B, S), 1, blk1, 0)
-        fpm1, _ = _vscan.viterbi_scan_packed_window(
-            code, p1, t1, b0, b1, rb, l1, h1, blk1, interpret
+        fpm1, _ = _vscan.viterbi_scan(
+            code, t1, b0, b1, rb, blk1, interpret, pm0=p1, window=(l1, h1)
         )
         # map[b, p, i, j] = best metric entering tile p in state i, leaving j
         maps = fpm1[:, :lanes1].reshape(S, B, P, S).transpose(2, 1, 3, 0)
@@ -347,8 +255,8 @@ def _tiled_weighted_decode(
     p2, _ = pad_axis_to(pm0, 1, blk2, NEG_UNREACHABLE)
     l2, _ = pad_axis_to(_tile_lane_row(lo_np, B), 1, blk2, 0)
     h2, _ = pad_axis_to(_tile_lane_row(hi_np, B), 1, blk2, 0)
-    fpm2, packed2 = _vscan.viterbi_scan_packed_window(
-        code, p2, t2, b0, b1, rb, l2, h2, blk2, interpret
+    fpm2, packed2 = _vscan.viterbi_scan(
+        code, t2, b0, b1, rb, blk2, interpret, pm0=p2, window=(l2, h2)
     )
     packed2 = packed2[:, :, :lanes2]  # (ceil(V/32), S, B*P)
     if not exact:
@@ -446,7 +354,7 @@ def bcjr_llr_op(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Max-log-MAP SISO decode of one RSC code block (kernels/bcjr.py).
 
-    The SISO analogue of viterbi_decode_fused: forward (alpha) scan with
+    The SISO analogue of viterbi_decode_packed: forward (alpha) scan with
     VMEM-resident metrics, then a time-reversed backward scan that fuses the
     beta recursion with per-step LLR extraction.
 

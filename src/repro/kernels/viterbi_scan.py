@@ -6,12 +6,12 @@ they never round-trip to HBM, exactly like the microcoded Texpand keeps its
 operands out of the fetch/decode path.  The grid iterates (batch-tile, time);
 TPU grid execution is sequential, so scratch carries state across time steps.
 
-One parameterized kernel body (`_make_scan_kernel`) serves every variant —
-the old block/carry pair were byte-identical except for their init path:
+One entry (:func:`viterbi_scan`) and one parameterized kernel body
+(`_make_scan_kernel`) serve every caller — block, streaming and tiled:
 
-  init path      ``carry=False`` seeds pm = [0, +inf, ...] in-kernel (paper
-                 §IV-B, paths start in state 0); ``carry=True`` seeds from a
-                 pm0 input (the streaming chunk scan).
+  init path      ``pm0=None`` seeds pm = [0, +inf, ...] in-kernel (paper
+                 §IV-B, paths start in state 0); a ``pm0`` input seeds the
+                 carried metrics (the streaming chunk scan, the tiled launch).
   branch metrics the per-step input is a generic ``(F, bB)`` tile multiplied
                  by an ``(S, F)`` weight pair plus an ``(S, 2)`` bias.  With
                  weights = the branch one-hots and F = n_symbols this is the
@@ -21,13 +21,11 @@ the old block/carry pair were byte-identical except for their init path:
                  received symbols **in-kernel**, cutting the per-step HBM
                  read from M·B to F·B floats (M = 2^n symbols vs F = n raw
                  values per step).
-  survivors      ``pack=False`` emits one int32 per (t, state, stream) —
-                 one useful bit per 4 bytes.  ``pack=True`` accumulates the
-                 ACS select bits in a uint32 scratch word and emits
-                 ``(ceil(T/32), S, B)`` — a 32× smaller survivor tensor that
-                 kernels/survivors.py traces back without ever unpacking in
-                 HBM.
-  validity       ``windowed=True`` adds per-lane int32 ``(lo, hi)`` rows: a
+  survivors      the ACS select bits accumulate in a uint32 scratch word and
+                 leave as ``(ceil(T/32), S, B)`` packed words — one bit per
+                 (step, state, stream), which kernels/survivors.py traces
+                 back without ever unpacking in HBM.
+  validity       a ``window`` adds per-lane int32 ``(lo, hi)`` rows: a
                  lane only runs ACS on steps ``lo <= t < hi`` and passes its
                  path metrics through unchanged (survivor bit forced 0)
                  elsewhere.  This is what lets the tiled decoder fold P
@@ -35,8 +33,8 @@ the old block/carry pair were byte-identical except for their init path:
                  ragged T%P / T%32 tails) into one uniform batched launch —
                  see kernels/tiling.py.
 
-Per grid step:  data tile (F, bB) streams in;  bp tile (S, bB) — or, packed,
-                1/32nd of one — streams out;  pm (S, bB) lives in scratch.
+Per grid step:  data tile (F, bB) streams in;  1/32nd of a packed survivor
+                tile (S, bB) streams out;  pm (S, bB) lives in scratch.
 """
 from __future__ import annotations
 
@@ -52,12 +50,11 @@ from repro.core.trellis import NEG_UNREACHABLE, ConvCode
 from repro.kernels.common import PACK_BITS, resolve_interpret
 
 
-def _make_scan_kernel(carry: bool, pack: bool, windowed: bool = False):
-    """Build the ACS scan kernel for one (init path, survivor format,
-    validity) combo.
+def _make_scan_kernel(carry: bool, windowed: bool = False):
+    """Build the ACS scan kernel for one (init path, validity) combo.
 
     Ref order: p0, p1, b0, b1, rb, [pm0], [lo, hi], data, out_bp, out_pm,
-    pm_scratch, [pack_scratch].
+    pm_scratch, pack_scratch.
     """
 
     def kernel(*refs):
@@ -66,7 +63,7 @@ def _make_scan_kernel(carry: bool, pack: bool, windowed: bool = False):
         del refs[:5]
         pm0_ref = refs.pop(0) if carry else None
         lo_ref, hi_ref = (refs.pop(0), refs.pop(0)) if windowed else (None, None)
-        data_ref, out_bp_ref, out_pm_ref, pm_scratch = refs[:4]
+        data_ref, out_bp_ref, out_pm_ref, pm_scratch, pack_scratch = refs
         t = pl.program_id(1)
 
         @pl.when(t == 0)
@@ -105,20 +102,16 @@ def _make_scan_kernel(carry: bool, pack: bool, windowed: bool = False):
         pm_scratch[...] = new_pm
         out_pm_ref[...] = new_pm.astype(out_pm_ref.dtype)
 
-        if pack:
-            pack_scratch = refs[4]
-            pos = (t % PACK_BITS).astype(jnp.uint32)
-            bit = take1.astype(jnp.uint32) << pos
-            # pos == 0 starts a fresh word (the masked read of uninitialized
-            # scratch on the first step is discarded by the where)
-            word = jnp.where(pos == 0, jnp.uint32(0), pack_scratch[...]) | bit
-            pack_scratch[...] = word
-            # the out tile stays VMEM-resident for 32 steps (its block index
-            # is t // 32); the value at the window's last visit — the fully
-            # packed word — is what lands in HBM.
-            out_bp_ref[0] = word
-        else:
-            out_bp_ref[0] = take1.astype(out_bp_ref.dtype)
+        pos = (t % PACK_BITS).astype(jnp.uint32)
+        bit = take1.astype(jnp.uint32) << pos
+        # pos == 0 starts a fresh word (the masked read of uninitialized
+        # scratch on the first step is discarded by the where)
+        word = jnp.where(pos == 0, jnp.uint32(0), pack_scratch[...]) | bit
+        pack_scratch[...] = word
+        # the out tile stays VMEM-resident for 32 steps (its block index
+        # is t // 32); the value at the window's last visit — the fully
+        # packed word — is what lands in HBM.
+        out_bp_ref[0] = word
 
     return kernel
 
@@ -132,10 +125,9 @@ def _scan_call(
     rb: jnp.ndarray,
     block_b: int,
     interpret: Optional[bool],
-    pack: bool,
     window: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Shared pallas_call plumbing for all the scan variants."""
+    """The pallas_call plumbing of :func:`viterbi_scan`."""
     T, F, B = data.shape
     S = code.n_states
     P0, P1 = code.select_matrices
@@ -154,20 +146,17 @@ def _scan_call(
             args.append(w.astype(jnp.int32))
     in_specs.append(pl.BlockSpec((1, F, block_b), lambda b, t: (t, 0, b)))
     args.append(data)
-    if pack:
-        n_words = pl.cdiv(T, PACK_BITS)
-        bp_spec = pl.BlockSpec(
-            (1, S, block_b), lambda b, t: (t // PACK_BITS, 0, b)
-        )
-        bp_shape = jax.ShapeDtypeStruct((n_words, S, B), jnp.uint32)
-    else:
-        bp_spec = pl.BlockSpec((1, S, block_b), lambda b, t: (t, 0, b))
-        bp_shape = jax.ShapeDtypeStruct((T, S, B), jnp.int32)
-    scratch = [pltpu.VMEM((S, block_b), jnp.float32)]
-    if pack:
-        scratch.append(pltpu.VMEM((S, block_b), jnp.uint32))
+    n_words = pl.cdiv(T, PACK_BITS)
+    bp_spec = pl.BlockSpec(
+        (1, S, block_b), lambda b, t: (t // PACK_BITS, 0, b)
+    )
+    bp_shape = jax.ShapeDtypeStruct((n_words, S, B), jnp.uint32)
+    scratch = [
+        pltpu.VMEM((S, block_b), jnp.float32),
+        pltpu.VMEM((S, block_b), jnp.uint32),
+    ]
     bps, final_pm = pl.pallas_call(
-        _make_scan_kernel(carry, pack, windowed=window is not None),
+        _make_scan_kernel(carry, windowed=window is not None),
         grid=grid,
         in_specs=in_specs,
         out_specs=[bp_spec, pl.BlockSpec((S, block_b), lambda b, t: (0, b))],
@@ -186,50 +175,8 @@ def table_weights(code: ConvCode):
     return jnp.asarray(OH0), jnp.asarray(OH1), rb
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2, 3))
-def viterbi_scan(
-    code: ConvCode,
-    bm_tables: jnp.ndarray,
-    block_b: int = 128,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Run all T ACS steps with VMEM-resident path metrics.
-
-    Args:
-      bm_tables: (T, M, B) float32.  B must be a multiple of ``block_b``.
-    Returns:
-      final_pm: (S, B) float32; bps: (T, S, B) int32 backpointer parities.
-    """
-    b0, b1, rb = table_weights(code)
-    return _scan_call(code, None, bm_tables, b0, b1, rb, block_b, interpret, pack=False)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 3, 4))
-def viterbi_scan_carry(
-    code: ConvCode,
-    pm0: jnp.ndarray,
-    bm_tables: jnp.ndarray,
-    block_b: int = 128,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Chunked ACS scan with carried state: run C steps starting from ``pm0``.
-
-    The streaming subsystem calls this once per chunk: pm0 is the previous
-    chunk's final path metrics, so a stream of arbitrary length runs through
-    the same VMEM-resident scan without re-materializing history.
-
-    Args:
-      pm0: (S, B) float32 path metrics entering the chunk.
-      bm_tables: (C, M, B) float32.  B must be a multiple of ``block_b``.
-    Returns:
-      final_pm: (S, B) float32; bps: (C, S, B) int32 backpointer parities.
-    """
-    b0, b1, rb = table_weights(code)
-    return _scan_call(code, pm0, bm_tables, b0, b1, rb, block_b, interpret, pack=False)
-
-
 @functools.partial(jax.jit, static_argnums=(0, 5, 6))
-def viterbi_scan_packed(
+def viterbi_scan(
     code: ConvCode,
     data: jnp.ndarray,
     b0: jnp.ndarray,
@@ -237,8 +184,14 @@ def viterbi_scan_packed(
     rb: jnp.ndarray,
     block_b: int = 128,
     interpret: Optional[bool] = None,
+    pm0: Optional[jnp.ndarray] = None,
+    window: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Forward scan with bit-packed survivors and generic in-kernel metrics.
+
+    The one entry into the kernel: the block decode (``pm0=None``), the
+    streaming chunk scan (``pm0`` carried from the previous chunk) and the
+    tiled launch (``pm0`` and ``window``).
 
     Args:
       data: (T, F, B) per-step inputs — precomputed bm tables (F = M,
@@ -247,56 +200,17 @@ def viterbi_scan_packed(
         one-hots).  B must be a multiple of ``block_b``.
       b0, b1: (S, F) float32 per-parity metric weights.
       rb: (S, 2) float32 per-parity metric bias.
+      pm0: optional (S, B) float32 path metrics entering the scan (held
+        untouched through any leading invalid steps of a window); None
+        starts every path in state 0.
+      window: optional ``(lo, hi)``, each (1, B) int32 — lane b runs ACS
+        only on steps lo[b] <= t < hi[b]; elsewhere the metrics pass through
+        and the survivor bit is 0 (kernels/tiling.py folds P time-tiles into
+        the lane axis this way).
     Returns:
       final_pm: (S, B) float32.
       packed: (ceil(T/32), S, B) uint32 — bit p of word w is the ACS select
         of trellis step ``t = 32*w + p`` (tail bits of a partial last word
         are zero).
     """
-    return _scan_call(code, None, data, b0, b1, rb, block_b, interpret, pack=True)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 6, 7))
-def viterbi_scan_packed_carry(
-    code: ConvCode,
-    pm0: jnp.ndarray,
-    data: jnp.ndarray,
-    b0: jnp.ndarray,
-    b1: jnp.ndarray,
-    rb: jnp.ndarray,
-    block_b: int = 128,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """:func:`viterbi_scan_packed` seeded from carried path metrics — the
-    streaming hot path (pm0: (S, B) float32 entering the chunk)."""
-    return _scan_call(code, pm0, data, b0, b1, rb, block_b, interpret, pack=True)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 8, 9))
-def viterbi_scan_packed_window(
-    code: ConvCode,
-    pm0: jnp.ndarray,
-    data: jnp.ndarray,
-    b0: jnp.ndarray,
-    b1: jnp.ndarray,
-    rb: jnp.ndarray,
-    lo: jnp.ndarray,
-    hi: jnp.ndarray,
-    block_b: int = 128,
-    interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """:func:`viterbi_scan_packed_carry` with a per-lane step-validity window
-    — the tiled-decode launch (kernels/tiling.py folds P time-tiles into the
-    lane axis; each lane's tile covers a different slice of the sequence).
-
-    Args:
-      pm0: (S, B) float32 metrics entering each lane's window (held
-        untouched through any leading invalid steps).
-      lo, hi: (1, B) int32 — lane b runs ACS only on steps lo[b] <= t <
-        hi[b]; elsewhere the metrics pass through and the survivor bit is 0.
-    Returns: final_pm (S, B) float32; packed (ceil(T/32), S, B) uint32.
-    """
-    return _scan_call(
-        code, pm0, data, b0, b1, rb, block_b, interpret, pack=True,
-        window=(lo, hi),
-    )
+    return _scan_call(code, pm0, data, b0, b1, rb, block_b, interpret, window)

@@ -1,7 +1,7 @@
 """Serving launcher: batched generation + the Viterbi decode path.
 
   python -m repro.launch.serve --arch qwen2_5_3b --smoke --tokens 32
-  python -m repro.launch.serve --viterbi --bits 256 --batch 64 --backend fused
+  python -m repro.launch.serve --viterbi --bits 256 --batch 64 --backend fused_packed
   python -m repro.launch.serve --viterbi --backend auto   # planner picks
 """
 from __future__ import annotations

@@ -169,11 +169,13 @@ class StreamScheduler:
         its ``terminated`` flag is the per-stream default.
       n_slots: decode-block batch size (compile-once; streams beyond this
         queue FIFO until a slot frees).
-      chunk: trellis steps per tick per slot.
+      chunk: trellis steps per tick per slot (a multiple of 32 for the
+        packed backend).
       depth: truncated-traceback depth (default 5*K; rounded up to a
         multiple of 32 for the packed backend).
-      backend: 'fused' | 'fused_packed' | 'scan' forward pass for the hot
-        loop ('fused_packed': bit-packed survivor ring + Pallas traceback).
+      backend: 'fused_packed' (default: Pallas scan, bit-packed survivor
+        ring + Pallas traceback; chunk % 32 == 0) | 'scan' (jnp reference)
+        forward pass for the hot loop.
       inputs: 'bm' — chunks are (t, M) branch-metric rows; 'received'
         (fused_packed only) — chunks are raw (t, n_out) channel symbols and
         branch metrics are computed in-kernel.
@@ -209,7 +211,7 @@ class StreamScheduler:
         n_slots: int = 64,
         chunk: int = 64,
         depth: Optional[int] = None,
-        backend: str = "fused",
+        backend: str = _w.PACKED_BACKEND,
         normalize: bool = True,
         interpret: Optional[bool] = None,
         inputs: str = "bm",

@@ -8,13 +8,14 @@ Memory is O(depth + chunk) regardless of stream length; path metrics are
 renormalized every chunk so float32 never saturates, with the accumulated
 offset tracked so ``finish`` still reports the absolute path metric.
 
-Backends: ``fused``/``scan`` consume (B, chunk, M) branch-metric tables.
-``fused_packed`` runs the memory-lean pipeline — bit-packed survivor ring,
-on-device traceback — and with ``inputs="received"`` consumes raw
-(B, chunk, n_out) channel symbols, computing branch metrics in-kernel
-(kernels/metrics.py).  The packed ring shifts whole uint32 words, so the
-chunk must be a multiple of 32 and the depth is rounded up to one (a deeper
-window only helps accuracy; the lag grows accordingly).
+Backends: ``fused_packed`` (the default) runs the memory-lean pipeline —
+bit-packed survivor ring, on-device traceback — on (B, chunk, M)
+branch-metric tables, or with ``inputs="received"`` on raw (B, chunk, n_out)
+channel symbols, computing branch metrics in-kernel (kernels/metrics.py).
+The packed ring shifts whole uint32 words, so the chunk must be a multiple
+of 32 and the depth is rounded up to one (a deeper window only helps
+accuracy; the lag grows accordingly).  ``scan`` is the jnp reference on
+branch-metric tables, at any chunk and depth.
 
 Typical use:
 
@@ -51,8 +52,8 @@ class StreamSession:
       depth: truncated-traceback depth D; bits commit D steps behind the
         frontier.  Default 5*K (the textbook rule); rounded up to a multiple
         of 32 for the packed backend.
-      backend: 'fused' (Pallas), 'fused_packed' (packed survivors +
-        on-device traceback), or 'scan' (jnp reference).
+      backend: 'fused_packed' (Pallas scan, packed survivors + on-device
+        traceback; chunk % 32 == 0) or 'scan' (jnp reference).
       inputs: 'bm' — push takes (B, chunk, M) branch-metric tables;
         'received' (fused_packed only) — push takes raw (B, chunk, n_out)
         channel symbols and the kernel computes the metrics.
@@ -75,7 +76,7 @@ class StreamSession:
         batch: int = 1,
         chunk: int = 64,
         depth: Optional[int] = None,
-        backend: str = "fused",
+        backend: str = _w.PACKED_BACKEND,
         normalize: bool = True,
         interpret: Optional[bool] = None,
         inputs: str = "bm",

@@ -9,24 +9,23 @@ a stream of any length (Martina & Masera 2010, §Viterbi traceback units).
 This module is the jittable core shared by sessions and the scheduler:
 
   StreamState     pytree carried across chunks: path metrics (B, S) and a
-                  backpointer ring buffer — (R, B, S) int32 for the unpacked
-                  backends, (R/32, B, S) uint32 packed survivor words for
-                  ``fused_packed`` (R = depth + chunk).
-  stream_step     advance C trellis steps (fused Pallas chunk scan, the
-                  packed-survivor scan, or a lax.scan reference), shift the
-                  ring, traceback from the frontier, and commit the C oldest
-                  window positions.
+                  backpointer ring buffer — (R/32, B, S) uint32 packed
+                  survivor words for ``fused_packed``, (R, B, S) int32 for
+                  the ``scan`` reference (R = depth + chunk).
+  stream_step     advance C trellis steps (the packed-survivor Pallas scan,
+                  or a lax.scan reference), shift the ring, traceback from
+                  the frontier, and commit the C oldest window positions.
   stream_flush    final traceback over the whole ring at end of stream.
   viterbi_decode_windowed
                   offline (B, T, M) -> (B, T) decode through the streaming
                   machinery — the equivalence oracle used by the tests.
 
-Backends: ``fused`` (Pallas chunk scan, unpacked int32 ring, XLA traceback),
-``scan`` (jnp reference), and ``fused_packed`` — the memory-lean hot path:
-bit-packed survivor ring (32× smaller), word-aligned ring shifts (requires
+Backends (``STREAM_BACKENDS``): ``fused_packed``, the default and the hot
+path — bit-packed survivor ring, word-aligned ring shifts (requires
 chunk % 32 == 0 and depth % 32 == 0, sessions round the depth up), Pallas
 traceback over the packed words, and optional in-kernel branch metrics when
-the caller feeds raw received symbols + folded metric weights.
+the caller feeds raw received symbols + folded metric weights; and ``scan``,
+the jnp reference (unpacked int32 ring, XLA traceback, any chunk).
 
 Exactness: when depth >= T nothing commits before the flush, the ring holds
 the whole history, and the flush traceback from the terminated state IS the
@@ -52,6 +51,8 @@ BIG = jnp.float32(NEG_UNREACHABLE)
 DEPTH_MULTIPLIER = 5  # the textbook truncation rule: D = 5 * constraint
 
 PACKED_BACKEND = "fused_packed"
+#: the stream ``backend`` values: the Pallas hot path and the jnp reference
+STREAM_BACKENDS = (PACKED_BACKEND, "scan")
 
 
 def default_depth(code: ConvCode) -> int:
@@ -72,6 +73,10 @@ def resolve_stream_backend(spec, chunk: int, depth: int, backend: str, inputs: s
     for the packed backend (None otherwise); ``weights`` its folded kernel
     operands when raw symbols are fed (None -> bm-table weights).
     """
+    if backend not in STREAM_BACKENDS:
+        raise ValueError(
+            f"stream backend must be one of {STREAM_BACKENDS}, got {backend!r}"
+        )
     packed = backend == PACKED_BACKEND
     if inputs not in ("bm", "received"):
         raise ValueError(f"inputs must be 'bm' or 'received', got {inputs!r}")
@@ -206,8 +211,9 @@ def init_stream_state(
 def chunk_forward_scan(
     code: ConvCode, pm: jnp.ndarray, bm_chunk: jnp.ndarray
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """lax.scan reference for the chunked forward pass (oracle for the fused
-    kernels.ops chunk ops, and the path used for odd-length stream tails).
+    """lax.scan reference for the chunked forward pass (the ``scan`` stream
+    backend, the oracle for the carried Pallas scan, and the path used for
+    odd-length stream tails).
     pm: (B, S); bm_chunk: (B, C, M) -> (new_pm, bps (C, B, S)).
     """
 
@@ -224,7 +230,7 @@ def stream_step(
     chunk_inputs: jnp.ndarray,
     weights=None,
     active: Optional[jnp.ndarray] = None,
-    backend: str = "fused",
+    backend: str = PACKED_BACKEND,
     normalize: bool = True,
     interpret: Optional[bool] = None,
     counters: Optional[DeviceCounters] = None,
@@ -235,7 +241,7 @@ def stream_step(
       chunk_inputs: (B, C, M) branch metrics — or, for the packed backend
         with in-kernel metrics, (B, C, F) raw features matching ``weights``.
       weights: (b0, b1, rb) folded metric weights for ``fused_packed``
-        (None -> the bm-table weights; ignored by the other backends).
+        (None -> the bm-table weights; ignored by ``scan``).
       active: optional (B,) bool mask — rows where it is False keep their
         pm/ring/offset EXACTLY as they were (the batched kernel still runs
         over them, but its result is discarded row-wise).  This is how the
@@ -244,9 +250,8 @@ def stream_step(
         NOT a no-op (the ACS min mixes predecessor metrics and pushes
         garbage backpointers into the ring), so masked slots must be
         re-selected, not just fed zeros.  None == all rows active.
-      backend: 'fused' (Pallas chunk scan), 'fused_packed' (packed
-        survivors + in-kernel metrics + Pallas traceback; C % 32 == 0), or
-        'scan' (jnp reference).
+      backend: 'fused_packed' (packed survivors + in-kernel metrics +
+        Pallas traceback; C % 32 == 0) or 'scan' (jnp reference).
       normalize: subtract the per-stream min from the path metrics so an
         unbounded stream never overflows float32; the subtracted offset is
         returned so callers can reconstruct absolute metrics.
@@ -282,20 +287,15 @@ def stream_step(
         best = jnp.argmin(new_pm, axis=-1).astype(jnp.int32)
         R = ring.shape[0] * PACK_BITS
         bits = viterbi_traceback_op(code, ring, best, R, interpret)  # (B, R)
-    else:
-        if backend == "fused":
-            from repro.kernels.ops import viterbi_forward_chunk_op
-
-            new_pm, bps = viterbi_forward_chunk_op(code, pm, chunk_inputs, interpret)
-        elif backend == "scan":
-            new_pm, bps = chunk_forward_scan(code, pm, chunk_inputs)
-        else:
-            raise KeyError(backend)
+    elif backend == "scan":
+        new_pm, bps = chunk_forward_scan(code, pm, chunk_inputs)
         ring = jnp.concatenate([ring[C:], bps], axis=0)
         # truncated traceback: from the best frontier state back through the
         # whole window; only the positions >= depth behind the frontier commit.
         best = jnp.argmin(new_pm, axis=-1).astype(jnp.int32)
         bits, _ = _traceback(code, ring, best)  # (B, R)
+    else:
+        raise KeyError(backend)
     committed = bits[:, :C]
 
     if normalize:
@@ -367,7 +367,7 @@ def make_sharded_stream_step(
     axis: str,
     *,
     chunk: int,
-    backend: str = "fused",
+    backend: str = PACKED_BACKEND,
     normalize: bool = True,
     interpret: Optional[bool] = None,
     weights=None,
@@ -486,7 +486,7 @@ def make_sharded_stream_step(
 @functools.lru_cache(maxsize=None)
 def jitted_stream_step(
     code: ConvCode,
-    backend: str = "fused",
+    backend: str = PACKED_BACKEND,
     normalize: bool = True,
     interpret: Optional[bool] = None,
 ):
@@ -571,7 +571,7 @@ def viterbi_decode_windowed(
     depth: Optional[int] = None,
     chunk: int = 64,
     terminated: Optional[bool] = None,
-    backend: str = "fused",
+    backend: str = PACKED_BACKEND,
     normalize: bool = True,
     interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:

@@ -15,8 +15,7 @@ def _workload():
         "workload": {"constraint": 7, "n_states": 64, "batch": 8, "steps": 90},
         "backends": {
             name: {"bits_per_s": 1e6}
-            for name in ("sequential", "fused", "fused_packed",
-                         "fused_packed_received")
+            for name in ("sequential", "fused_packed", "fused_packed_received")
         },
         "survivor_bytes": {"shrink_x": 30.0},
         "speedup": {
@@ -146,8 +145,8 @@ def _payload():
                      "violation_lines": []},
             "jaxpr": {
                 "contracts": {
-                    "fused": {"backend": "fused", "equations": 69,
-                              "violations": 0},
+                    "fused_packed": {"backend": "fused_packed",
+                                     "equations": 69, "violations": 0},
                     "sharded_stream_tick": {"backend": "sharded_stream",
                                             "equations": 913, "violations": 0},
                 },
@@ -355,10 +354,10 @@ def test_check_schema_rejects_broken_long_blocks_sections(mutate):
         lambda p: p["analysis"]["jaxpr"].__setitem__("violations", 1),
         # a registered backend with no hot-path contract trace
         lambda p: p["analysis"]["jaxpr"].__setitem__("backends_registered", 3),
-        lambda p: p["analysis"]["jaxpr"]["contracts"]["fused"].__setitem__(
+        lambda p: p["analysis"]["jaxpr"]["contracts"]["fused_packed"].__setitem__(
             "violations", 2
         ),
-        lambda p: p["analysis"]["jaxpr"]["contracts"]["fused"].__setitem__(
+        lambda p: p["analysis"]["jaxpr"]["contracts"]["fused_packed"].__setitem__(
             "equations", 0
         ),
         lambda p: p["analysis"]["jaxpr"].__setitem__("contracts", {}),

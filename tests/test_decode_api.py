@@ -32,7 +32,7 @@ from repro.parallel.mesh import make_mesh
 
 GRID_CODES = {"k3": CODE_K3_STD, "k7": CODE_K7_NASA}
 EXPECTED_BACKENDS = (
-    "bcjr", "fused", "fused_packed", "parallel", "seqparallel", "sequential",
+    "bcjr", "fused_packed", "parallel", "seqparallel", "sequential",
     "sharded_stream", "streaming", "tiled", "turbo",
 )
 #: the Viterbi backends the bit-exact equivalence grid sweeps (same family,
@@ -120,14 +120,16 @@ def test_registry_rejects_duplicates_and_unknown():
 
     with pytest.raises(KeyError, match="registered"):
         reg.get("nope")
-    with pytest.raises(KeyError, match="fused"):
+    with pytest.raises(KeyError, match="fused_packed"):
         get_decoder("no-such-backend")
+    with pytest.raises(KeyError):
+        get_decoder("fused")  # the unpacked-survivor backend is gone
 
 
 def test_capability_records():
     assert get_decoder("seqparallel").capabilities.requires_mesh
     assert get_decoder("streaming").capabilities.supports_streaming
-    assert get_decoder("fused").capabilities.max_states is not None
+    assert get_decoder("fused_packed").capabilities.max_states is not None
     caps = get_decoder("sharded_stream").capabilities
     assert caps.sharded_stream and caps.requires_mesh and caps.supports_streaming
     for name in CONV_BACKENDS:
@@ -164,10 +166,11 @@ def test_backend_equivalence_grid(code_name, punctured, metric, terminated,
     ref_bits, ref_metric = viterbi_decode(code, bm, terminated=terminated)
 
     for name in CONV_BACKENDS:
-        needs_mesh = get_decoder(name).capabilities.requires_mesh
+        caps = get_decoder(name).capabilities
         ctx = DecodeContext(
-            mesh=mesh11 if needs_mesh else None,
-            chunk=16,
+            mesh=mesh11 if caps.requires_mesh else None,
+            # the packed stream ring shifts whole 32-step words
+            chunk=32 if caps.supports_streaming else 16,
             stream_depth=T,  # window covers the block -> exactness regime
         )
         res = get_decoder(name)(spec, bm, ctx=ctx)
@@ -353,6 +356,12 @@ def test_sharded_stream_backend_validation(mesh11):
         plan_decode(CodecSpec(), (8, 64), backend="sharded_stream", mesh=model_only)
     plan = plan_decode(CodecSpec(), (8, 64), backend="sharded_stream", mesh=mesh11)
     assert plan.backend == "sharded_stream"
+    # the packed ring needs whole 32-step words: no silent fallback
+    bm = jnp.zeros((8, 64, CodecSpec().code.n_symbols), jnp.float32)
+    with pytest.raises(ValueError, match="chunk % 32"):
+        get_decoder("sharded_stream")(
+            CodecSpec(), bm, ctx=DecodeContext(mesh=mesh11, chunk=16)
+        )
 
 
 def test_planner_override_and_validation(mesh11):

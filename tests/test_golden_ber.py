@@ -47,14 +47,15 @@ K7_BATCH = 16
 K7_INFO_BITS = 96
 K7_FLIPS = (0.02, 0.06, 0.11)  # clean floor -> waterfall knee -> lossy region
 #: every decode path whose quality the file pins: the oracle, the (min,+)
-#: scan, the packed Pallas pipeline, the truncated-window streamer, and the
-#: time-parallel tiled decoder (P=4 exact seams — must sit exactly on the
-#: sequential curve).
+#: scan, the packed Pallas pipeline on bm tables and on the received symbols
+#: (in-kernel metrics, ``fused_packed_received``), the truncated-window
+#: streamer, and the time-parallel tiled decoder (P=4 exact seams — must sit
+#: exactly on the sequential curve).
 K7_BACKENDS = (
     "sequential",
     "parallel",
-    "fused",
     "fused_packed",
+    "fused_packed_received",
     "streaming",
     "tiled",
 )
@@ -90,8 +91,14 @@ def compute_k7_payload():
         bm = spec.branch_metrics(rx)
         row = {}
         for name in K7_BACKENDS:
-            ctx = DecodeContext(chunk=16, tiles=4 if name == "tiled" else None)
-            res = get_decoder(name)(spec, bm, ctx=ctx)
+            if name == "fused_packed_received":  # decode() from the symbols
+                res = decode(spec, rx, backend="fused_packed")
+            else:
+                ctx = DecodeContext(
+                    chunk=32 if name == "streaming" else 16,  # whole words
+                    tiles=4 if name == "tiled" else None,
+                )
+                res = get_decoder(name)(spec, bm, ctx=ctx)
             row[name] = float((np.asarray(res.info_bits) != truth).mean())
         grid[f"{flip:g}"] = row
     return {

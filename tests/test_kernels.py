@@ -12,8 +12,16 @@ from hypothesis import strategies as st
 
 from repro.core import CODE_K3_PAPER, CODE_K3_STD, CODE_K5_GSM, CODE_K7_NASA
 from repro.core.trellis import NEG_UNREACHABLE
-from repro.kernels import minplus_matmul_op, texpand_op, viterbi_decode_fused, viterbi_forward_op
+from repro.kernels import (
+    minplus_matmul_op,
+    texpand_op,
+    unpack_survivors,
+    viterbi_decode_packed,
+    viterbi_forward_weighted_op,
+)
+from repro.kernels.common import PACK_BITS
 from repro.kernels.ref import minplus_matmul_ref, texpand_ref, viterbi_scan_ref
+from repro.kernels.viterbi_scan import table_weights
 
 CODES = {"k3": CODE_K3_STD, "k3p": CODE_K3_PAPER, "k5": CODE_K5_GSM, "k7": CODE_K7_NASA}
 
@@ -46,23 +54,34 @@ def test_texpand_tie_break(rng):
 
 
 # --------------------------------------------------------------------------- #
-# viterbi_scan (full-sequence fused forward)                                   #
+# viterbi_scan (full-sequence forward, packed survivors)                       #
 # --------------------------------------------------------------------------- #
 
 
 @pytest.mark.parametrize("code_id", ["k3", "k5", "k7"])
-@pytest.mark.parametrize("B,T", [(1, 4), (8, 31), (130, 16)])
+@pytest.mark.parametrize(
+    "B,T",  # lane padding (130), partial last words, whole words
+    [(1, 4), (8, 31), (130, 16), (1, 7), (8, 64), (130, 33)],
+)
 def test_viterbi_scan_matches_ref(code_id, B, T, rng):
+    """The packed forward scan: final metrics and every survivor bit (once
+    unpacked) equal the one-step oracle iterated T times."""
     code = CODES[code_id]
     M, S = code.n_symbols, code.n_states
     bm = jax.random.uniform(rng, (B, T, M), jnp.float32, 0, 2)
-    final_pm, bps = viterbi_forward_op(code, bm)
+    final_pm, packed = viterbi_forward_weighted_op(
+        code, None, bm, table_weights(code)
+    )
+    assert packed.shape == (-(-T // PACK_BITS), B, S)
     pm0 = jnp.full((S, B), NEG_UNREACHABLE, jnp.float32).at[0].set(0.0)
     ref_pm, ref_bps = viterbi_scan_ref(code, bm.transpose(1, 2, 0), pm0)
     ref_pm = jnp.minimum(ref_pm, NEG_UNREACHABLE)
     np.testing.assert_allclose(
         np.asarray(final_pm), np.asarray(ref_pm.T), rtol=1e-5)
-    assert (np.asarray(bps) == np.asarray(ref_bps.transpose(0, 2, 1))).all()
+    np.testing.assert_array_equal(
+        np.asarray(unpack_survivors(packed, T)),
+        np.asarray(ref_bps.transpose(0, 2, 1)),
+    )
 
 
 def test_fused_decoder_equals_reference_decoder(rng):
@@ -74,7 +93,7 @@ def test_fused_decoder_equals_reference_decoder(rng):
     rx = bsc(jax.random.fold_in(rng, 1), coded, 0.03)
     bm = hard_branch_metrics(code, rx)
     d_ref, m_ref = viterbi_decode(code, bm)
-    d_fused, m_fused = viterbi_decode_fused(code, bm)
+    d_fused, m_fused = viterbi_decode_packed(code, bm)
     assert jnp.allclose(m_ref, m_fused)
     assert (d_ref == d_fused).all()
 

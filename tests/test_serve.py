@@ -33,7 +33,7 @@ def test_engine_greedy_is_deterministic(rng):
     assert (a == b).all()
 
 
-@pytest.mark.parametrize("backend", ["fused", "sequential", "parallel"])
+@pytest.mark.parametrize("backend", ["fused_packed", "sequential", "parallel"])
 def test_decode_transport_roundtrip(backend, rng):
     spec = CodecSpec()
     bits = jax.random.bernoulli(rng, 0.5, (8, 64)).astype(jnp.int32)

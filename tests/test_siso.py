@@ -338,7 +338,7 @@ def test_planner_conv_selection_is_unchanged_by_siso_families():
 
 def test_family_mismatch_is_a_validation_error():
     with pytest.raises(ValueError, match="family"):
-        plan_decode(TSPEC, (4, 64), backend="fused")
+        plan_decode(TSPEC, (4, 64), backend="fused_packed")
     with pytest.raises(ValueError, match="family"):
         plan_decode(CodecSpec(), (4, 64), backend="turbo")
     with pytest.raises(ValueError, match="family"):

@@ -12,7 +12,8 @@ from repro.core import (
     hard_branch_metrics,
     viterbi_decode,
 )
-from repro.kernels.ops import viterbi_forward_chunk_op, viterbi_forward_op
+from repro.kernels import unpack_survivors, viterbi_forward_weighted_op
+from repro.kernels.viterbi_scan import table_weights
 from repro.stream import (
     StreamScheduler,
     StreamSession,
@@ -41,23 +42,26 @@ def _noisy_bm(code, key, batch, info_bits, flip):
 def test_chunked_forward_matches_full_scan(code_name, rng):
     """Composing carried-state chunk scans == one full-block forward pass."""
     code = CODES[code_name]
+    w = table_weights(code)
     _, bm = _noisy_bm(code, rng, 4, 61, 0.05)
-    full_pm, full_bps = viterbi_forward_op(code, bm)
+    T = bm.shape[1]
+    full_pm, full_packed = viterbi_forward_weighted_op(code, None, bm, w)
 
     pm = init_stream_state(code, 4, 1, 1).pm
     bps_parts = []
     C = 16
-    T = bm.shape[1]
     for i in range(0, T, C):
         chunk = bm[:, i : i + C]
         if chunk.shape[1] == C:
-            pm, bps = viterbi_forward_chunk_op(code, pm, chunk)
+            pm, packed = viterbi_forward_weighted_op(code, pm, chunk, w)
+            bps = unpack_survivors(packed, C)
         else:  # odd tail goes through the scan reference
             pm, bps = chunk_forward_scan(code, pm, chunk)
         bps_parts.append(bps)
     np.testing.assert_allclose(np.asarray(pm), np.asarray(full_pm), rtol=1e-6)
     np.testing.assert_array_equal(
-        np.concatenate([np.asarray(b) for b in bps_parts]), np.asarray(full_bps)
+        np.concatenate([np.asarray(b) for b in bps_parts]),
+        np.asarray(unpack_survivors(full_packed, T)),
     )
 
 
@@ -65,10 +69,12 @@ def test_chunk_op_matches_scan_reference(rng):
     code = CODE_K3_STD
     _, bm = _noisy_bm(code, rng, 8, 30, 0.1)
     pm0 = init_stream_state(code, 8, 1, 1).pm
-    pm_f, bps_f = viterbi_forward_chunk_op(code, pm0, bm)
+    pm_f, packed = viterbi_forward_weighted_op(code, pm0, bm, table_weights(code))
     pm_s, bps_s = chunk_forward_scan(code, pm0, bm)
     np.testing.assert_allclose(np.asarray(pm_f), np.asarray(pm_s), rtol=1e-6)
-    np.testing.assert_array_equal(np.asarray(bps_f), np.asarray(bps_s))
+    np.testing.assert_array_equal(
+        np.asarray(unpack_survivors(packed, bm.shape[1])), np.asarray(bps_s)
+    )
 
 
 # --------------------------------------------------------------------------- #
@@ -77,7 +83,7 @@ def test_chunk_op_matches_scan_reference(rng):
 
 
 @pytest.mark.parametrize("code_name", sorted(CODES))
-@pytest.mark.parametrize("backend", ["scan", "fused"])
+@pytest.mark.parametrize("backend", ["scan", "fused_packed"])
 def test_windowed_bit_exact_when_depth_covers_block(code_name, backend, rng):
     code = CODES[code_name]
     _, bm = _noisy_bm(code, rng, 4, 96 - (code.constraint - 1), 0.04)
@@ -367,6 +373,10 @@ def test_packed_session_rounds_depth_and_handles_odd_tail(rng):
     np.testing.assert_allclose(np.asarray(metric), np.asarray(ref_metric), rtol=1e-5)
     with pytest.raises(ValueError, match="chunk"):
         StreamSession(code, chunk=20, backend="fused_packed")
+    with pytest.raises(ValueError, match="chunk"):
+        StreamSession(code, chunk=20)  # the default backend is the packed one
+    with pytest.raises(ValueError, match="stream backend"):
+        StreamSession(code, chunk=32, backend="fused")  # no unpacked Pallas ring
 
 
 def test_packed_session_from_received_in_kernel_metrics(rng):

@@ -15,8 +15,6 @@ from repro.kernels import (
     fused_metric_plan,
     pack_survivors,
     unpack_survivors,
-    viterbi_forward_op,
-    viterbi_forward_packed_op,
     viterbi_traceback_op,
 )
 from repro.kernels.common import PACK_BITS
@@ -82,21 +80,8 @@ def test_pack_tail_bits_are_zero():
 
 
 # --------------------------------------------------------------------------- #
-# kernel packing == helper packing; Pallas traceback == XLA traceback          #
+# Pallas traceback == XLA traceback                                            #
 # --------------------------------------------------------------------------- #
-
-
-@pytest.mark.parametrize("code_id", sorted(CODES))
-@pytest.mark.parametrize("B,T", [(1, 7), (8, 64), (130, 33)])  # lane padding + tails
-def test_packed_forward_matches_unpacked(code_id, B, T, rng):
-    code = CODES[code_id]
-    bm = jax.random.uniform(rng, (B, T, code.n_symbols), jnp.float32, 0, 2)
-    pm_u, bps = viterbi_forward_op(code, bm)  # (T, B, S) unpacked
-    pm_p, packed = viterbi_forward_packed_op(code, bm)
-    np.testing.assert_allclose(np.asarray(pm_p), np.asarray(pm_u), rtol=1e-6)
-    np.testing.assert_array_equal(
-        np.asarray(packed), np.asarray(pack_survivors(bps))
-    )
 
 
 @pytest.mark.parametrize("code_id", sorted(CODES))

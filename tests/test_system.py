@@ -27,7 +27,7 @@ def test_paper_workload_end_to_end(rng):
         stream = ViterbiStream(ARCH.code, shape.n_info_bits, batch=8,
                                flip_prob=0.02)
         batch = stream(0)
-        res = get_decoder("fused")(spec, batch["bm_tables"], ctx=DecodeContext())
+        res = get_decoder("fused_packed")(spec, batch["bm_tables"], ctx=DecodeContext())
         ber = float((res.info_bits != batch["info_bits"]).mean())
         assert ber < 0.2, (shape.name, ber)
 

@@ -46,11 +46,7 @@ from repro.kernels import (
     viterbi_decode_tiled_op,
 )
 from repro.kernels.common import lane_block, pad_axis_to
-from repro.kernels.viterbi_scan import (
-    table_weights,
-    viterbi_scan_packed_carry,
-    viterbi_scan_packed_window,
-)
+from repro.kernels.viterbi_scan import table_weights, viterbi_scan
 from repro.parallel.collectives import _local_transfer_and_bps
 
 try:  # the property layer widens coverage when hypothesis is available
@@ -292,12 +288,10 @@ def test_windowed_scan_full_window_matches_carry_scan(rng):
     B, T = 3, 45
     bm, pm0, bm_p, pm0_p, blk = _packed_fixture(code, rng, B, T)
     b0, b1, rb = table_weights(code)
-    ref_pm, ref_packed = viterbi_scan_packed_carry(
-        code, pm0_p, bm_p, b0, b1, rb, blk
-    )
+    ref_pm, ref_packed = viterbi_scan(code, bm_p, b0, b1, rb, blk, pm0=pm0_p)
     full = jnp.zeros((1, bm_p.shape[2]), jnp.int32)
-    win_pm, win_packed = viterbi_scan_packed_window(
-        code, pm0_p, bm_p, b0, b1, rb, full, full + T, blk
+    win_pm, win_packed = viterbi_scan(
+        code, bm_p, b0, b1, rb, blk, pm0=pm0_p, window=(full, full + T)
     )
     np.testing.assert_array_equal(np.asarray(win_pm[:, :B]),
                                   np.asarray(ref_pm[:, :B]))
@@ -313,13 +307,11 @@ def test_windowed_scan_partial_window_matches_sliced_scan(rng):
     bm, pm0, bm_p, pm0_p, blk = _packed_fixture(code, rng, B, T)
     b0, b1, rb = table_weights(code)
     ones = jnp.ones((1, bm_p.shape[2]), jnp.int32)
-    win_pm, win_packed = viterbi_scan_packed_window(
-        code, pm0_p, bm_p, b0, b1, rb, ones * lo, ones * hi, blk
+    win_pm, win_packed = viterbi_scan(
+        code, bm_p, b0, b1, rb, blk, pm0=pm0_p, window=(ones * lo, ones * hi)
     )
     sl_p, _ = pad_axis_to(bm[lo:hi], 2, blk, 0.0)
-    ref_pm, ref_packed = viterbi_scan_packed_carry(
-        code, pm0_p, sl_p, b0, b1, rb, blk
-    )
+    ref_pm, ref_packed = viterbi_scan(code, sl_p, b0, b1, rb, blk, pm0=pm0_p)
     np.testing.assert_array_equal(np.asarray(win_pm[:, :B]),
                                   np.asarray(ref_pm[:, :B]))
     # bits inside the window line up step-for-step; outside they are zero

@@ -19,13 +19,14 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.trellis import CODE_K3_STD, CODE_K7_NASA
 from repro.decode import CodecSpec
 from repro.kernels import bcjr, minplus, survivors, texpand, viterbi_scan
+from repro.kernels.metrics import fused_metric_plan
 from repro.siso.rsc import RSC_K4_LTE
 
 LANES = 128
 B = 1024  # eight 128-lane blocks: the grid's batch axis has more than one step
 T = 1030  # the tpu_nasa_frame block: 1024 info bits + 6 flush bits, K=7
 CODES = {"S64": CODE_K7_NASA, "S4": CODE_K3_STD}
-SCAN_VARIANTS = ("plain", "carry", "packed", "packed_carry", "window")
+SCAN_VARIANTS = ("tables", "received", "tables_carry", "received_carry", "window")
 
 
 @pytest.fixture(scope="module")
@@ -59,10 +60,10 @@ def _sds(chip, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
-def _compile_has_kernel(fn, *args):
+def _compile_has_kernel(fn, *args, **kwargs):
     """Lower + compile for the described chip; assert a Mosaic kernel is in
     the compiled program (an interpret-mode fallback would have none)."""
-    compiled = fn.lower(*args).compile()
+    compiled = fn.lower(*args, **kwargs).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
 
@@ -70,23 +71,24 @@ def _compile_has_kernel(fn, *args):
 @pytest.mark.parametrize("variant", SCAN_VARIANTS)
 @pytest.mark.parametrize("states", sorted(CODES))
 def test_forward_scan_compiles(chip, states, variant):
+    """The one forward entry, as each caller drives it: bm tables (F = M)
+    or raw received symbols (F = the soft metric plan's features — the
+    wifi_bcc34 block path), from state 0 or from carried metrics (the
+    dvbs_r12 tick is ``received_carry``), and the tiled window."""
     code = CODES[states]
-    S, M = code.n_states, code.n_symbols
-    data = _sds(chip, (T, M, B))
-    pm0 = _sds(chip, (S, B))
-    w = (_sds(chip, (S, M)), _sds(chip, (S, M)), _sds(chip, (S, 2)))
-    lo_hi = (_sds(chip, (1, B), jnp.int32), _sds(chip, (1, B), jnp.int32))
-    fn, args = {
-        "plain": (viterbi_scan.viterbi_scan, (code, data, LANES, False)),
-        "carry": (viterbi_scan.viterbi_scan_carry, (code, pm0, data, LANES, False)),
-        "packed": (viterbi_scan.viterbi_scan_packed,
-                   (code, data, *w, LANES, False)),
-        "packed_carry": (viterbi_scan.viterbi_scan_packed_carry,
-                         (code, pm0, data, *w, LANES, False)),
-        "window": (viterbi_scan.viterbi_scan_packed_window,
-                   (code, pm0, data, *w, *lo_hi, LANES, False)),
-    }[variant]
-    _compile_has_kernel(fn, *args)
+    S = code.n_states
+    if variant.startswith("received"):
+        F = fused_metric_plan(code, "soft", None).n_features
+    else:
+        F = code.n_symbols
+    data = _sds(chip, (T, F, B))
+    w = (_sds(chip, (S, F)), _sds(chip, (S, F)), _sds(chip, (S, 2)))
+    kw = {}
+    if variant.endswith("carry") or variant == "window":
+        kw["pm0"] = _sds(chip, (S, B))
+    if variant == "window":
+        kw["window"] = (_sds(chip, (1, B), jnp.int32), _sds(chip, (1, B), jnp.int32))
+    _compile_has_kernel(viterbi_scan.viterbi_scan, code, data, *w, LANES, False, **kw)
 
 
 @pytest.mark.parametrize("states", sorted(CODES))
